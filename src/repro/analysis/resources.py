@@ -100,7 +100,8 @@ def crosscheck_decode_state(cfg, res) -> list[Finding]:
         )]
     b = 1
     h = res.grid[1]
-    k = res.grid[2]
+    # The whole K-token window is one block: K is the r block's seq dim.
+    k = dict((n, s) for n, s, _ in res.blocks)["r"][2]
     costs = {c.variant: c for c in cost_model.wkv_decode_traffic(b, h, dh, k)}
     tok_io = cost_model.wkv_decode_token_io(b, h, dh, k)
     modeled = costs["direct"].traffic.dram_bytes - tok_io

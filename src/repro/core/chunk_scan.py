@@ -190,7 +190,7 @@ def device_linear_scan_carry(
     elevator — the adjoint carry ``dS`` of a forward recurrence flows
     exactly this way during sequence-sharded training.
     """
-    n = device_comm.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     sgn = -1 if reverse else 1
     acc_a, acc_b = a_seg, b_seg
     d = 1

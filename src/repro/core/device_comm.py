@@ -17,18 +17,11 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "axis_size",
     "device_shift",
     "halo_exchange",
     "ring_pass",
     "seq_carry_scan",
 ]
-
-
-def axis_size(axis_name: str) -> int:
-    # jax 0.4.x has no jax.lax.axis_size; psum of a literal 1 over the named
-    # axis folds to the static mesh size inside shard_map.
-    return jax.lax.psum(1, axis_name)
 
 
 def device_shift(x: jax.Array, axis_name: str, delta: int = 1, fill=0.0) -> jax.Array:
@@ -37,7 +30,7 @@ def device_shift(x: jax.Array, axis_name: str, delta: int = 1, fill=0.0) -> jax.
     Boundary shards (no producer) receive ``fill`` — the elevator constant C.
     Exactly one collective-permute; O(|x|) bytes point-to-point on ICI.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if delta == 0:
         return x
     perm = [(i, i + delta) for i in range(n) if 0 <= i + delta < n]
@@ -54,7 +47,7 @@ def ring_pass(x: jax.Array, axis_name: str, delta: int = 1) -> jax.Array:
     Used by ring-style forwarding (e.g. rotating K/V or operand tiles so a
     value loaded from HBM once visits every shard — the eLDST pattern).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + delta) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name, perm)
 
@@ -116,7 +109,7 @@ def seq_carry_scan(
     — the sweep direction of adjoint carries (e.g. the WKV ``dS``) during
     sequence-sharded training.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     # Position along the sweep: hop k activates the k-th shard in sweep
     # order (ascending indices forward, descending in reverse).

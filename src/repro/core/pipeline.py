@@ -77,10 +77,8 @@ def pipeline_apply(
     init_in = jnp.zeros(buf_shape, x_micro.dtype)
     init_out = jnp.zeros_like(x_micro)
     # The loop-carried buffers become shard-varying after the first ppermute;
-    # mark them varying up front so the scan carry types are stable.  jax
-    # 0.4.x has no pvary (no varying-axis types either) — identity there.
-    pvary = getattr(jax.lax, "pvary", lambda v, _axes: v)
-    init_in = pvary(init_in, (axis_name,))
-    init_out = pvary(init_out, (axis_name,))
+    # mark them varying up front so the scan carry types are stable.
+    init_in = jax.lax.pcast(init_in, (axis_name,), to="varying")
+    init_out = jax.lax.pcast(init_out, (axis_name,), to="varying")
     (_, outputs), _ = jax.lax.scan(tick, (init_in, init_out), jnp.arange(ticks))
     return outputs

@@ -37,8 +37,8 @@ from jax.experimental import pallas as pl
 __all__ = [
     "on_tpu",
     "interpret_default",
-    "shard_map_norep",
     "reset_carry",
+    "head_row_spec",
     "reversed_chunk",
     "shift_rows",
     "cumsum_rows",
@@ -68,30 +68,6 @@ def interpret_default() -> bool:
     return not on_tpu()
 
 
-def shard_map_norep(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions.
-
-    jax 0.4.x spells the flag ``check_rep``; newer jax renamed it
-    ``check_vma`` (and moved shard_map out of experimental — the
-    experimental import path still works on both).  Used by the
-    sequence-parallel kernel wrappers, whose replicated outputs come from
-    masked psums the checker cannot always see through.
-    """
-    import inspect
-
-    from jax.experimental.shard_map import shard_map
-
-    check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{check_kw: False},
-    )
-
-
 # --------------------------------------------------------------------------
 # In-kernel carry helpers
 # --------------------------------------------------------------------------
@@ -117,6 +93,17 @@ def reset_carry(carry_ref, value=None, *, seq_axis: int = 2) -> None:
             carry_ref[...] = jnp.zeros_like(carry_ref)
         else:
             carry_ref[...] = value.astype(carry_ref.dtype)
+
+
+def head_row_spec(dh: int) -> pl.BlockSpec:
+    """BlockSpec of a per-head row vector stored ``(H, 1, Dh)``, on a grid
+    whose second axis walks the heads.
+
+    The TPU lowering requires the last two block dims to be multiples of
+    (8, 128) or equal to the array's; ``(1, Dh)`` equals them for any Dh,
+    where the natural ``(1, Dh)`` block over an ``(H, Dh)`` array does not.
+    """
+    return pl.BlockSpec((1, 1, dh), lambda bi, hi, *_: (hi, 0, 0))
 
 
 def reversed_chunk(n_chunks: int):

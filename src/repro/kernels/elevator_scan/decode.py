@@ -5,12 +5,13 @@ Stateful decode used to force the unfused jnp path
 (``elevator_scan(..., use_kernel=False if t == 1 else None)`` in
 ``model/recurrent.py``), so the (B, d_rnn) hidden state round-tripped HBM
 on every generated token even on TPU.  Here the window of K decode steps
-is swept in ONE kernel invocation on a ``(batch, d_blocks, K)`` grid with
-``h`` held in a VMEM scratch — the same Δ=1 elevator carry the chunked
-kernel uses over chunk space, now over *decode steps*: one HBM read of
-``h0`` and one write of the exit state per K tokens instead of per token.
-K is arbitrary (no chunk structure, no divisibility constraint); K == 1
-is the classic single-token step.
+is swept in ONE kernel invocation on a ``(batch, d_blocks)`` grid: the
+whole window is one block and a loop inside the kernel carries ``h`` from
+token to token — the same Δ=1 elevator carry the chunked kernel uses over
+chunk space, now over *decode steps*: one HBM read of ``h0`` and one
+write of the exit state per K tokens instead of per token.  K is
+arbitrary up to :data:`ELEVATOR_DECODE_WINDOW_MAX` (no chunk structure,
+no divisibility constraint); K == 1 is the classic single-token step.
 
 Differentiable through :func:`elevator_decode_diff` (recompute-over-stage:
 the backward is the closed-form adjoint of the linear recurrence — a
@@ -28,9 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import pick_d_block, reset_carry
+from repro.kernels.common import pick_d_block
 from repro.kernels.elevator_scan.ref import elevator_scan_ref_f32
 
 # Stateful (decode) dispatches at or below this many tokens take the
@@ -45,19 +45,22 @@ __all__ = [
 ]
 
 
-def elevator_decode_window_kernel(a_ref, x_ref, h0_ref, out_ref, h_ref):
-    """K-step window, grid (batch, d_blocks, K): h rides the VMEM scratch.
+def elevator_decode_window_kernel(a_ref, x_ref, h0_ref, out_ref):
+    """K-step window, grid (batch, d_blocks): the whole window is one block.
 
-    Grid step ``i`` withdraws the state deposited by step ``i-1`` (step 0
-    withdraws the boundary constant ``h0``) — the elevator hand-off of
-    the chunked kernel with decode steps as the chunk axis.
+    An in-kernel loop walks the K tokens; step ``i`` withdraws the state
+    deposited by step ``i-1`` (step 0 withdraws the boundary constant
+    ``h0``) — the elevator hand-off of the chunked kernel with decode
+    steps as the chunk axis.
     """
-    reset_carry(h_ref, h0_ref[...], seq_axis=2)
-    a = a_ref[0].astype(jnp.float32)                    # (1, d_block)
-    x = x_ref[0].astype(jnp.float32)
-    h = a * h_ref[...] + x
-    out_ref[0] = h.astype(out_ref.dtype)
-    h_ref[...] = h                                      # hand-off: TID -> TID+1
+    h = h0_ref[0].astype(jnp.float32)                   # (1, d_block)
+    # Static unroll (K <= ELEVATOR_DECODE_WINDOW_MAX): the TPU lowering
+    # refuses a dynamic single-row index into a block, a static one it takes.
+    for i in range(a_ref.shape[1]):
+        row = slice(i, i + 1)
+        h = (a_ref[0, row, :].astype(jnp.float32) * h
+             + x_ref[0, row, :].astype(jnp.float32))    # hand-off: TID -> TID+1
+        out_ref[0, row, :] = h.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -70,27 +73,30 @@ def elevator_decode_window_pallas(
 ) -> jax.Array:
     """K-token decode window of h[t] = a[t]*h[t-1] + x[t].
 
-    a/x: (B, K, D), any K >= 1; h0: (B, D).  Returns h (B, K, D) in
-    ``x.dtype`` — bit-identical to K single steps chained, with one HBM
-    round-trip of the state instead of K.
+    a/x: (B, K, D), 1 <= K <= ELEVATOR_DECODE_WINDOW_MAX; h0: (B, D).
+    Returns h (B, K, D) in ``x.dtype`` — bit-identical to K single steps
+    chained, with one HBM round-trip of the state instead of K.
     """
     b, t, d = x.shape
     if h0.shape != (b, d):
         raise ValueError(f"h0 shape {h0.shape} != {(b, d)}")
+    if t > ELEVATOR_DECODE_WINDOW_MAX:
+        raise ValueError(f"decode window K={t} > {ELEVATOR_DECODE_WINDOW_MAX}"
+                         f": the kernel unrolls K")
     d_block = pick_d_block(d)
-    seq_spec = pl.BlockSpec((1, 1, d_block), lambda bi, di, ti: (bi, ti, di))
+    seq_spec = pl.BlockSpec((1, t, d_block), lambda bi, di: (bi, 0, di))
     return pl.pallas_call(
         elevator_decode_window_kernel,
-        grid=(b, d // d_block, t),
+        grid=(b, d // d_block),
         in_specs=[
             seq_spec, seq_spec,
-            pl.BlockSpec((1, d_block), lambda bi, di, ti: (bi, di)),
+            # h0 as (B, 1, D): see elevator_scan_pallas.
+            pl.BlockSpec((1, 1, d_block), lambda bi, di: (bi, 0, di)),
         ],
         out_specs=seq_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, d_block), jnp.float32)],
         interpret=interpret,
-    )(a, x, h0)
+    )(a, x, h0[:, None])
 
 
 # --------------------------------------------------------------------------

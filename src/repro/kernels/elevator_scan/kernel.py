@@ -59,7 +59,7 @@ def elevator_scan_kernel(
     a_ref, x_ref, h0_ref, out_ref, carry_ref, *, chunk: int, n_chunks: int
 ):
     # Boundary: chunk 0 receives the elevator constant h0.
-    reset_carry(carry_ref, h0_ref[...], seq_axis=2)
+    reset_carry(carry_ref, h0_ref[0], seq_axis=2)
 
     a = a_ref[0].astype(jnp.float32)   # (chunk, d_block)
     x = x_ref[0].astype(jnp.float32)
@@ -92,6 +92,8 @@ def elevator_scan_pallas(
     n_chunks = t // chunk
     if h0 is None:
         h0 = jnp.zeros((b, d), x.dtype)
+    if h0.shape != (b, d):
+        raise ValueError(f"h0 shape {h0.shape} != {(b, d)}")
 
     grid = (b, d // d_block, n_chunks)
     kernel = functools.partial(
@@ -103,10 +105,12 @@ def elevator_scan_pallas(
         in_specs=[
             pl.BlockSpec((1, chunk, d_block), lambda bi, di, si: (bi, si, di)),
             pl.BlockSpec((1, chunk, d_block), lambda bi, di, si: (bi, si, di)),
-            pl.BlockSpec((1, d_block), lambda bi, di, si: (bi, di)),
+            # h0 as (B, 1, D): the trailing (1, d_block) block's row dim
+            # equals the array's, which the TPU tiling rule requires.
+            pl.BlockSpec((1, 1, d_block), lambda bi, di, si: (bi, 0, di)),
         ],
         out_specs=pl.BlockSpec((1, chunk, d_block), lambda bi, di, si: (bi, si, di)),
         out_shape=jax.ShapeDtypeStruct((b, t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, d_block), jnp.float32)],
         interpret=interpret,
-    )(a, x, h0)
+    )(a, x, h0[:, None])

@@ -162,7 +162,7 @@ def _elevator_fwd_resources(cfg, *, t: int = 4096, chunk: int = 256):
         grid=(1, d // d_block, t // c),
         blocks=(
             ("a", seq, isz), ("x", seq, isz),
-            ("h0", (1, d_block), 4), ("out", seq, isz),
+            ("h0", (1, 1, d_block), 4), ("out", seq, isz),
         ),
         scratch=(("h", (1, d_block), 4),),
     )
@@ -170,19 +170,19 @@ def _elevator_fwd_resources(cfg, *, t: int = 4096, chunk: int = 256):
 
 @register_kernel_resources("elevator_scan.decode_window")
 def _elevator_decode_resources(cfg, *, window: int = ELEVATOR_DECODE_WINDOW_MAX):
-    """Persistent-state decode window: h rides VMEM across the window."""
+    """Persistent-state decode window: the whole window is one block, h
+    rides an in-kernel loop across it."""
     if "rec" not in tuple(cfg.pattern):
         return None
     d, d_block, isz = _elevator_geometry(cfg)
-    seq = (1, 1, d_block)
+    seq = (1, window, d_block)
     return KernelResources(
         kernel="elevator_scan.decode_window",
         location=("src/repro/kernels/elevator_scan/decode.py:"
                   "elevator_decode_window_pallas"),
-        grid=(1, d // d_block, window),
+        grid=(1, d // d_block),
         blocks=(
             ("a", seq, isz), ("x", seq, isz),
-            ("h0", (1, d_block), 4), ("out", seq, isz),
+            ("h0", (1, 1, d_block), 4), ("out", seq, isz),
         ),
-        scratch=(("h", (1, d_block), 4),),
     )

@@ -6,17 +6,18 @@ import functools
 
 import jax
 
-from repro.kernels.common import KernelResources, register_kernel_resources
+from repro.kernels.common import (
+    KernelResources,
+    interpret_default,
+    on_tpu,
+    register_kernel_resources,
+)
 from repro.kernels.local_attention.kernel import (
     DEFAULT_BLOCK_K,
     DEFAULT_BLOCK_Q,
     flash_attention_pallas,
 )
 from repro.kernels.local_attention.ref import attention_blockwise, attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # NOTE: intentionally un-jitted — called under the model's outer jit; a
@@ -38,11 +39,11 @@ def flash_attention(
     reflects the kernel's memory/flop profile — and the exact masked-einsum
     reference for short ones.  All three agree numerically (tests).
     """
-    kernel = _on_tpu() if use_kernel is None else use_kernel
+    kernel = on_tpu() if use_kernel is None else use_kernel
     if kernel:
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, scale=scale,
-            interpret=not _on_tpu(),
+            interpret=interpret_default(),
         )
     if q.shape[2] > 1024 or k.shape[2] > 1024:
         from repro.core.lowering import scan_unroll

@@ -7,12 +7,9 @@ import functools
 import jax
 
 from repro.core.cost_model import Traffic
+from repro.kernels.common import interpret_default, on_tpu
 from repro.kernels.matmul_fwd.kernel import matmul_fwd_pallas
 from repro.kernels.matmul_fwd.ref import matmul_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # NOTE: intentionally un-jitted — called under the model's outer jit; a
@@ -20,11 +17,11 @@ def _on_tpu() -> bool:
 def matmul_fwd(
     a, b, *, block_m=256, block_n=256, block_k=256, use_kernel: bool | None = None
 ):
-    kernel = _on_tpu() if use_kernel is None else use_kernel
+    kernel = on_tpu() if use_kernel is None else use_kernel
     if kernel:
         return matmul_fwd_pallas(
             a, b, block_m=block_m, block_n=block_n, block_k=block_k,
-            interpret=not _on_tpu(),
+            interpret=interpret_default(),
         )
     return matmul_ref(a, b)
 

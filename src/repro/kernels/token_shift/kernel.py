@@ -43,7 +43,7 @@ def token_shift_kernel(x_ref, w_ref, out_ref, carry_ref, *, taps: int, chunk: in
     acc = w[0] * x
     for k in range(1, taps):
         # Rows [taps-1-k : taps-1-k+chunk] of ext == x shifted down by k.
-        shifted = jax.lax.dynamic_slice_in_dim(ext, taps - 1 - k, chunk, axis=0)
+        shifted = ext[taps - 1 - k:taps - 1 - k + chunk]
         acc = acc + w[k] * shifted
 
     out_ref[0, :, :] = acc.astype(out_ref.dtype)

@@ -41,11 +41,12 @@ from repro.kernels.wkv.ref import (
     wkv_chunked_ref,
     wkv_sequential_ref,
 )
-from repro.kernels.wkv.seqpar import wkv_seq_local, wkv_seqshard
+from repro.kernels.wkv.seqpar import wkv_headshard, wkv_seq_local, wkv_seqshard
 
 __all__ = [
     "wkv_fused",
     "wkv_fused_summary",
+    "wkv_headshard",
     "wkv_seq_local",
     "wkv_seqshard",
     "wkv_chunked_ref",

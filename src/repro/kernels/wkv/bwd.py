@@ -53,6 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
     cumsum_rows,
+    head_row_spec,
     reset_carry,
     rev_cumsum_rows,
     reversed_chunk,
@@ -76,7 +77,7 @@ def wkv_bwd_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)           # (dh,)
+    u = u_ref[0].astype(jnp.float32)           # (1, dh)
     do = do_ref[0, 0].astype(jnp.float32)      # (chunk, dh)
     S = s_hist_ref[0, 0, 0]                    # (dh, dh) entering state
     dS = ds_ref[...]                           # (dh, dh) exit-state adjoint
@@ -85,10 +86,11 @@ def wkv_bwd_kernel(
     logw = jnp.log(jnp.clip(w, 1e-8, 1.0))
     cum_incl = cumsum_rows(logw, chunk)
     cum_excl = cum_incl - logw
-    w_total = jnp.exp(cum_incl[-1])            # (dh,)
+    cum_last = cum_incl[chunk - 1:]            # (1, dh)
+    w_total = jnp.exp(cum_last)                # (1, dh)
     r_dec = r * jnp.exp(cum_excl)
     k_inv = k * jnp.exp(-cum_incl)
-    k_rem = k * jnp.exp(cum_incl[-1:] - cum_incl)
+    k_rem = k * jnp.exp(cum_last - cum_incl)
 
     ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
@@ -117,24 +119,24 @@ def wkv_bwd_kernel(
         preferred_element_type=jnp.float32,
     )
 
-    dr = d_rdec * jnp.exp(cum_excl) + u[None, :] * k * dov
+    dr = d_rdec * jnp.exp(cum_excl) + u * k * dov
     dk = (d_kinv * jnp.exp(-cum_incl)
-          + d_krem * jnp.exp(cum_incl[-1:] - cum_incl)
-          + r * u[None, :] * dov)
+          + d_krem * jnp.exp(cum_last - cum_incl)
+          + r * u * dov)
     dv = (jax.lax.dot_general(scores, do, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
           + jnp.dot(k_rem, dS, preferred_element_type=jnp.float32)
-          + jnp.sum(r * u[None, :] * k, axis=1, keepdims=True) * do)
+          + jnp.sum(r * u * k, axis=1, keepdims=True) * do)
 
     # logw adjoint: suffix sums (adjoint of the forward prefix sums), with
-    # the cum_incl[-1] consumers (k_rem numerator, w_total in the exit
+    # the cum_last consumers (k_rem numerator, w_total in the exit
     # decay) folded onto the last row first.
     dcum_excl = d_rdec * r_dec
     dcum_incl = -d_kinv * k_inv - d_krem * k_rem
-    last = (jnp.sum(d_krem * k_rem, axis=0)
-            + w_total * jnp.sum(S * dS, axis=1))            # (dh,)
+    last = (jnp.sum(d_krem * k_rem, axis=0, keepdims=True)
+            + w_total * jnp.sum(S * dS, axis=1, keepdims=True).T)  # (1, dh)
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, dh), 0)
-    dcum_incl = dcum_incl + jnp.where(row == chunk - 1, last[None, :], 0.0)
+    dcum_incl = dcum_incl + jnp.where(row == chunk - 1, last, 0.0)
     dlogw = (rev_cumsum_rows(dcum_incl, chunk)
              + rev_cumsum_rows(dcum_excl, chunk) - dcum_excl)
     in_range = (w >= 1e-8) & (w <= 1.0)
@@ -148,11 +150,11 @@ def wkv_bwd_kernel(
     # du partial: accumulate over this (batch, head) tile's chunks.
     du_acc_ref[...] = du_acc_ref[...] + jnp.sum(r * k * dov, axis=0,
                                                 keepdims=True)
-    du_ref[0, 0] = du_acc_ref[0]               # last grid step wins
+    du_ref[0, 0] = du_acc_ref[...]             # last grid step wins
 
     # Adjoint token hand-off (retag TID -> TID - 1): the entering-state
     # adjoint becomes the predecessor chunk's exit-state adjoint.
-    dS_prev = dS * w_total[:, None] + jax.lax.dot_general(
+    dS_prev = dS * w_total.T + jax.lax.dot_general(
         r_dec, do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -200,7 +202,7 @@ def wkv_pallas_bwd(
     )
     state_spec = pl.BlockSpec((1, 1, dh, dh), lambda bi, hi, si: (bi, hi, 0, 0))
     kernel = functools.partial(wkv_bwd_kernel, chunk=chunk)
-    return pl.pallas_call(
+    dr, dk, dv, dw, du, dh0 = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -208,7 +210,7 @@ def wkv_pallas_bwd(
             rev_seq,   # k
             rev_seq,   # v
             rev_seq,   # w
-            pl.BlockSpec((1, dh), lambda bi, hi, si: (hi, 0)),  # u
+            head_row_spec(dh),  # u
             rev_hist,  # s_hist (entry state per chunk)
             rev_seq,   # d_out
             state_spec,  # d_s_out (reverse boundary constant)
@@ -218,7 +220,9 @@ def wkv_pallas_bwd(
             rev_seq,   # dk
             rev_seq,   # dv
             rev_seq,   # dw
-            pl.BlockSpec((1, 1, dh), lambda bi, hi, si: (bi, hi, 0)),  # du
+            # du as (B, H, 1, Dh): a (1, Dh) trailing block equals the
+            # array's, where (1, 1, Dh) over (B, H, Dh) does not tile.
+            pl.BlockSpec((1, 1, 1, dh), lambda bi, hi, si: (bi, hi, 0, 0)),
             state_spec,  # dh0
         ),
         out_shape=(
@@ -226,7 +230,7 @@ def wkv_pallas_bwd(
             jax.ShapeDtypeStruct((b, h, t, dh), k.dtype),
             jax.ShapeDtypeStruct((b, h, t, dh), v.dtype),
             jax.ShapeDtypeStruct((b, h, t, dh), w.dtype),
-            jax.ShapeDtypeStruct((b, h, dh), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, dh), jnp.float32),
             jax.ShapeDtypeStruct((b, h, dh, dh), jnp.float32),
         ),
         scratch_shapes=[
@@ -234,4 +238,5 @@ def wkv_pallas_bwd(
             pltpu.VMEM((1, dh), jnp.float32),   # du accumulator
         ],
         interpret=interpret,
-    )(r, k, v, w, u, s_hist, d_out, d_s_out)
+    )(r, k, v, w, u.reshape(h, 1, dh), s_hist, d_out, d_s_out)
+    return dr, dk, dv, dw, du[:, :, 0], dh0

@@ -6,19 +6,18 @@ read and write the full (B, H, Dh, Dh) WKV state through HBM, because the
 re-dispatched per token.  These kernels are the paper's loop-carried-value
 argument applied to serving:
 
-* :func:`wkv_decode_pallas` — the single-step kernel on a ``(batch, head)``
-  grid.  One token: ``o = r · (S + u kᵀv)``, ``S' = diag(w) S + kᵀv``, f32
-  accumulation, bf16 I/O like the fused path.  No chunk machinery, no
-  score matrices — two rank-1 updates and a matvec, fused in one pass so
-  the state is read from HBM exactly once and written exactly once.
-* :func:`wkv_decode_window_pallas` — the multi-token variant: a
-  ``(B, H, K, Dh)`` window of K decode steps swept in ONE kernel
-  invocation on a ``(batch, head, K)`` grid with S held in a VMEM scratch
-  — the same Δ=1 elevator carry the chunked kernel uses over chunk space,
-  now over *decode steps*.  One HBM read + one write of S per K tokens
-  instead of per token; the K-1 intermediate states ride the fabric
-  (``cost_model.wkv_decode_traffic`` counts exactly these bytes).  K is
-  arbitrary (no divisibility constraint — there is no chunk structure).
+* :func:`wkv_decode_window_pallas` — a ``(B, H, K, Dh)`` window of K
+  decode steps swept in ONE kernel invocation on a ``(batch, head)`` grid.
+  The whole window is one block and a loop inside the kernel walks its
+  tokens, carrying S from token to token — the same Δ=1 elevator carry
+  the chunked kernel uses over chunk space, now over *decode steps*.  Per
+  token: ``o = r · (S + u kᵀv)``, ``S' = diag(w) S + kᵀv``, f32
+  accumulation, bf16 I/O like the fused path.  One HBM read + one write
+  of S per K tokens instead of per token; the K-1 intermediate states
+  never leave the core (``cost_model.wkv_decode_traffic`` counts exactly
+  these bytes).  K is arbitrary up to :data:`DECODE_WINDOW_MAX` (no
+  divisibility constraint — there is no chunk structure).
+* :func:`wkv_decode_pallas` — the same kernel at K = 1.
 
 Unlike the chunked kernel there is no decay-ratio factorization: the
 sequential form is exact and the per-step work is O(Dh²), so nothing is
@@ -41,9 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import reset_carry
+from repro.kernels.common import head_row_spec
 from repro.kernels.wkv.ref import wkv_chunked_bwd_ref, wkv_sequential_ref
 
 # Stateful (decode) dispatches at or below this many tokens take the
@@ -69,44 +67,37 @@ def _decode_token(r, k, v, w, u, S):
         k, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
     )                                                   # kᵀv: (dh, dh)
     inter = jnp.dot(r, S, preferred_element_type=jnp.float32)   # (1, dh)
-    bonus = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True)  # (1, 1)
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True)           # (1, 1)
     o = inter + bonus * v
-    S_new = S * w[0][:, None] + kv
+    S_new = S * w.T + kv
     return o, S_new
 
 
-def wkv_decode_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, h0_ref,
-                      out_ref, s_out_ref):
-    """Single step, grid (batch, head): state read once, written once."""
-    r = r_ref[0, 0].astype(jnp.float32)                 # (1, dh)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)                    # (dh,)
-    o, S_new = _decode_token(r, k, v, w, u, h0_ref[0, 0])
-    out_ref[0, 0] = o.astype(out_ref.dtype)
-    s_out_ref[0, 0] = S_new
-
-
 def wkv_decode_window_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, h0_ref,
-                             out_ref, s_out_ref, s_ref):
-    """K-step window, grid (batch, head, K): S rides the VMEM scratch.
+                             out_ref, s_out_ref):
+    """K-step window, grid (batch, head): the whole window is one block.
 
-    Grid step ``i`` withdraws the state deposited by step ``i-1`` (step 0
-    withdraws the boundary constant ``h0``) — the elevator hand-off of the
-    chunked kernel with decode steps as the chunk axis.  HBM sees one read
-    (``h0``) and one write (``s_out``, last grid step wins) per K tokens.
+    An in-kernel loop walks the K tokens; step ``i`` withdraws the state
+    deposited by step ``i-1`` (step 0 withdraws the boundary constant
+    ``h0``) — the elevator hand-off of the chunked kernel with decode
+    steps as the chunk axis.  HBM sees one read (``h0``) and one write
+    (``s_out``) of S per K tokens.
     """
-    reset_carry(s_ref, h0_ref[0, 0], seq_axis=2)
-    r = r_ref[0, 0].astype(jnp.float32)                 # (1, dh)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)
-    o, S_new = _decode_token(r, k, v, w, u, s_ref[...])
-    out_ref[0, 0] = o.astype(out_ref.dtype)
-    s_ref[...] = S_new                                  # hand-off: TID -> TID+1
-    s_out_ref[0, 0] = S_new                             # last grid step wins
+    u = u_ref[0].astype(jnp.float32)                    # (1, dh)
+    S = h0_ref[0, 0]
+    # Static unroll (K <= DECODE_WINDOW_MAX): the TPU lowering refuses a
+    # dynamic single-row index into a bf16 block, a static one it takes.
+    for i in range(r_ref.shape[2]):
+        row = slice(i, i + 1)
+        o, S = _decode_token(
+            r_ref[0, 0, row, :].astype(jnp.float32),
+            k_ref[0, 0, row, :].astype(jnp.float32),
+            v_ref[0, 0, row, :].astype(jnp.float32),
+            w_ref[0, 0, row, :].astype(jnp.float32),
+            u, S,
+        )                                               # hand-off: TID -> TID+1
+        out_ref[0, 0, row, :] = o.astype(out_ref.dtype)
+    s_out_ref[0, 0] = S
 
 
 def _validate(r, u, h0):
@@ -130,28 +121,11 @@ def wkv_decode_pallas(
 ):
     """Single decode step.  r/k/v/w: (B, H, 1, Dh); u: (H, Dh);
     h0: (B, H, Dh, Dh).  Returns (out (B,H,1,Dh) r.dtype, S (B,H,Dh,Dh) f32).
+    The window kernel at K = 1: state read once, written once.
     """
-    b, h, t, dh = r.shape
-    if t != 1:
-        raise ValueError(f"wkv_decode_pallas is single-step; got T={t}")
-    _validate(r, u, h0)
-    seq_spec = pl.BlockSpec((1, 1, 1, dh), lambda bi, hi: (bi, hi, 0, 0))
-    state_spec = pl.BlockSpec((1, 1, dh, dh), lambda bi, hi: (bi, hi, 0, 0))
-    return pl.pallas_call(
-        wkv_decode_kernel,
-        grid=(b, h),
-        in_specs=[
-            seq_spec, seq_spec, seq_spec, seq_spec,
-            pl.BlockSpec((1, dh), lambda bi, hi: (hi, 0)),  # u
-            state_spec,
-        ],
-        out_specs=(seq_spec, state_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, h, 1, dh), r.dtype),
-            jax.ShapeDtypeStruct((b, h, dh, dh), jnp.float32),
-        ),
-        interpret=interpret,
-    )(r, k, v, w, u, h0)
+    if r.shape[2] != 1:
+        raise ValueError(f"wkv_decode_pallas is single-step; got T={r.shape[2]}")
+    return _decode_window_call(r, k, v, w, u, h0, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -165,21 +139,30 @@ def wkv_decode_window_pallas(
     *,
     interpret: bool = False,
 ):
-    """K-token decode window.  r/k/v/w: (B, H, K, Dh), any K >= 1 (no
-    divisibility constraint); u: (H, Dh); h0: (B, H, Dh, Dh).  Returns
-    (out (B,H,K,Dh) r.dtype, S (B,H,Dh,Dh) f32) — bit-identical to K
-    single steps chained, with one HBM round-trip of S instead of K.
+    """K-token decode window.  r/k/v/w: (B, H, K, Dh),
+    1 <= K <= DECODE_WINDOW_MAX (no divisibility constraint); u: (H, Dh);
+    h0: (B, H, Dh, Dh).  Returns (out (B,H,K,Dh) r.dtype, S (B,H,Dh,Dh)
+    f32) — bit-identical to K single steps chained, with one HBM
+    round-trip of S instead of K.
     """
+    return _decode_window_call(r, k, v, w, u, h0, interpret=interpret)
+
+
+def _decode_window_call(r, k, v, w, u, h0, *, interpret):
     b, h, t, dh = r.shape
     _validate(r, u, h0)
-    seq_spec = pl.BlockSpec((1, 1, 1, dh), lambda bi, hi, ti: (bi, hi, ti, 0))
-    state_spec = pl.BlockSpec((1, 1, dh, dh), lambda bi, hi, ti: (bi, hi, 0, 0))
+    if t > DECODE_WINDOW_MAX:
+        raise ValueError(f"decode window K={t} > {DECODE_WINDOW_MAX}: the "
+                         f"kernel unrolls K, longer sweeps take the chunked "
+                         f"kernel")
+    seq_spec = pl.BlockSpec((1, 1, t, dh), lambda bi, hi: (bi, hi, 0, 0))
+    state_spec = pl.BlockSpec((1, 1, dh, dh), lambda bi, hi: (bi, hi, 0, 0))
     return pl.pallas_call(
         wkv_decode_window_kernel,
-        grid=(b, h, t),
+        grid=(b, h),
         in_specs=[
             seq_spec, seq_spec, seq_spec, seq_spec,
-            pl.BlockSpec((1, dh), lambda bi, hi, ti: (hi, 0)),  # u
+            head_row_spec(dh),  # u
             state_spec,
         ],
         out_specs=(seq_spec, state_spec),
@@ -187,9 +170,8 @@ def wkv_decode_window_pallas(
             jax.ShapeDtypeStruct((b, h, t, dh), r.dtype),
             jax.ShapeDtypeStruct((b, h, dh, dh), jnp.float32),
         ),
-        scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, h0)
+    )(r, k, v, w, u.reshape(h, 1, dh), h0)
 
 
 # --------------------------------------------------------------------------

@@ -43,7 +43,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cumsum_rows, reset_carry, validate_divisible
+from repro.kernels.common import (
+    cumsum_rows,
+    head_row_spec,
+    reset_carry,
+    validate_divisible,
+)
 
 
 def _wkv_fwd_body(
@@ -68,26 +73,27 @@ def _wkv_fwd_body(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)           # (dh,)
+    u = u_ref[0].astype(jnp.float32)           # (1, dh)
 
     # Decay-ratio factorization, all in registers/VMEM (nothing staged):
     #   cum_excl[t] = sum_{s<t} log w_s, w_total = prod over the chunk.
     logw = jnp.log(jnp.clip(w, 1e-8, 1.0))
     cum_incl = cumsum_rows(logw, chunk)
     cum_excl = cum_incl - logw
-    w_total = jnp.exp(cum_incl[-1])            # (dh,)
+    cum_last = cum_incl[chunk - 1:]            # (1, dh)
+    w_total = jnp.exp(cum_last)                # (1, dh)
 
     if a_acc_ref is not None:
         # Per-segment summary: A_seg = prod over every chunk's w_total — the
         # diag-decay half of the (A, S) pair that crosses the mesh axis in
         # the sequence-parallel protocol (seqpar.py).  Rides its own tiny
         # VMEM carry exactly like S.
-        a_acc_ref[...] = a_acc_ref[...] * w_total[None, :]
-        a_out_ref[0, 0] = a_acc_ref[0]         # last grid step wins
+        a_acc_ref[...] = a_acc_ref[...] * w_total
+        a_out_ref[0, 0] = a_acc_ref[...]       # last grid step wins
 
     r_dec = r * jnp.exp(cum_excl)              # r_t * D_{<t}
     k_inv = k * jnp.exp(-cum_incl)             # k_s / D_{<=s}
-    k_rem = k * jnp.exp(cum_incl[-1:] - cum_incl)  # k_s * D_{(s..L]}
+    k_rem = k * jnp.exp(cum_last - cum_incl)  # k_s * D_{(s..L]}
 
     # Intra-chunk attention: A[t,s] = (r_t D_{<t}) · (k_s / D_{<=s}), s < t,
     # plus the u-bonus on the diagonal.
@@ -98,7 +104,7 @@ def _wkv_fwd_body(
     ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(si < ti, scores, 0.0)
-    bonus = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True)  # (chunk, 1)
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True)  # (chunk, 1)
     intra = jnp.dot(scores, v, preferred_element_type=jnp.float32) + bonus * v
 
     # Inter-chunk read: withdraw the entering state token from VMEM.
@@ -107,7 +113,7 @@ def _wkv_fwd_body(
     out_ref[0, 0] = (intra + inter).astype(out_ref.dtype)
 
     # State update + token hand-off (retag TID -> TID + 1).
-    S_new = S * w_total[:, None] + jax.lax.dot_general(
+    S_new = S * w_total.T + jax.lax.dot_general(
         k_rem, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -183,8 +189,11 @@ def _wkv_pallas_call(r, k, v, w, u, h0, *, chunk, interpret, with_hist,
             jax.ShapeDtypeStruct((b, h, n_chunks, dh, dh), jnp.float32),
         )
     if with_summary:
-        out_specs += (pl.BlockSpec((1, 1, dh), lambda bi, hi, si: (bi, hi, 0)),)
-        out_shape += (jax.ShapeDtypeStruct((b, h, dh), jnp.float32),)
+        # (B, H, 1, Dh): the trailing (1, Dh) block equals the array's.
+        out_specs += (pl.BlockSpec(
+            (1, 1, 1, dh), lambda bi, hi, si: (bi, hi, 0, 0)
+        ),)
+        out_shape += (jax.ShapeDtypeStruct((b, h, 1, dh), jnp.float32),)
     kernels = {
         (False, False): wkv_kernel,
         (True, False): wkv_train_kernel,
@@ -195,7 +204,7 @@ def _wkv_pallas_call(r, k, v, w, u, h0, *, chunk, interpret, with_hist,
     scratch_shapes = [pltpu.VMEM((dh, dh), jnp.float32)]
     if with_summary:
         scratch_shapes.append(pltpu.VMEM((1, dh), jnp.float32))  # A_seg carry
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -203,14 +212,17 @@ def _wkv_pallas_call(r, k, v, w, u, h0, *, chunk, interpret, with_hist,
             seq_spec,  # k
             seq_spec,  # v
             seq_spec,  # w
-            pl.BlockSpec((1, dh), lambda bi, hi, si: (hi, 0)),  # u
+            head_row_spec(dh),  # u
             state_spec,  # h0
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
-    )(r, k, v, w, u, h0)
+    )(r, k, v, w, u.reshape(h, 1, dh), h0)
+    if with_summary:
+        outs = outs[:-1] + (outs[-1][:, :, 0],)
+    return outs
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

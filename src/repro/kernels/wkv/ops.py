@@ -80,12 +80,23 @@ def chunk_warning_scope(tag: str | None):
         _WARN_SCOPE.pop()
 
 
-def resolve_chunk(t: int, chunk: int, *, scope: str | None = None) -> int:
+def resolve_chunk(t: int, chunk: int, *, scope: str | None = None,
+                  tpu_kernel: bool = False) -> int:
     """Largest divisor of ``t`` no larger than ``chunk``; warns on adjust
-    (once per distinct ``(t, chunk)`` per warn scope)."""
+    (once per distinct ``(t, chunk)`` per warn scope).
+
+    ``tpu_kernel``: the chunk feeds the compiled TPU kernel, whose
+    ``(1, 1, chunk, Dh)`` blocks need ``chunk`` a multiple of 8 or all of
+    ``t``; a divisor that is neither raises instead of warning.
+    """
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
     c = largest_divisor_chunk(t, chunk)
+    if tpu_kernel and c % 8 and c != t:
+        raise ValueError(
+            f"wkv chunk={chunk} on T={t}: the largest divisor {c} is neither "
+            f"a multiple of 8 nor T, which the TPU kernel's tiling refuses"
+        )
     if c != min(chunk, t):
         key = scope if scope is not None else (
             _WARN_SCOPE[-1] if _WARN_SCOPE else None
@@ -149,7 +160,8 @@ def wkv_fused(
         # autodiff through a few steps is trivial.
         out, s_out = wkv_sequential_ref(r, k, v, w, u, h0)
         return out.astype(r.dtype), s_out
-    c = resolve_chunk(t, chunk, scope=warn_scope)
+    c = resolve_chunk(t, chunk, scope=warn_scope,
+                      tpu_kernel=kernel and not interpret_default())
     return wkv_diff(c, interpret_default(), bool(kernel), r, k, v, w, u, h0)
 
 
@@ -180,7 +192,8 @@ def wkv_fused_summary(
     if h0 is None:
         h0 = jnp.zeros((b, h, dh, dh), jnp.float32)
     kernel = on_tpu() if use_kernel is None else use_kernel
-    c = resolve_chunk(t, chunk, scope=warn_scope)
+    c = resolve_chunk(t, chunk, scope=warn_scope,
+                      tpu_kernel=kernel and not interpret_default())
     return wkv_diff_summary(
         c, interpret_default(), bool(kernel), r, k, v, w, u, h0
     )
@@ -222,7 +235,7 @@ def _wkv_fwd_resources(cfg, *, t: int = 4096, chunk: int = 64):
         grid=(1, h, t // c),
         blocks=(
             ("r", seq, isz), ("k", seq, isz), ("v", seq, isz),
-            ("w", seq, isz), ("u", (1, dh), isz), ("h0", state, 4),
+            ("w", seq, isz), ("u", (1, 1, dh), isz), ("h0", state, 4),
             ("out", seq, isz), ("s_out", state, 4),
         ),
         scratch=(("S", (dh, dh), 4),),
@@ -247,7 +260,8 @@ def _wkv_train_resources(cfg, *, t: int = 4096, chunk: int = 64):
 
 @register_kernel_resources("wkv.decode_window")
 def _wkv_decode_resources(cfg, *, window: int = DECODE_WINDOW_MAX):
-    """Persistent-state decode window: S rides VMEM across the window."""
+    """Persistent-state decode window: the whole window is one block, S
+    rides an in-kernel loop across it."""
     if "rwkv" not in tuple(cfg.pattern):
         return None
     import jax.numpy as jnp
@@ -260,16 +274,15 @@ def _wkv_decode_resources(cfg, *, window: int = DECODE_WINDOW_MAX):
     dh = _WKV_DH
     h = cfg.d_model // dh
     isz = jnp.dtype(cfg.dtype).itemsize
-    seq = (1, 1, 1, dh)
+    seq = (1, 1, window, dh)
     state = (1, 1, dh, dh)
     return KernelResources(
         kernel="wkv.decode_window",
         location="src/repro/kernels/wkv/decode.py:wkv_decode_window_pallas",
-        grid=(1, h, window),
+        grid=(1, h),
         blocks=(
             ("r", seq, isz), ("k", seq, isz), ("v", seq, isz),
-            ("w", seq, isz), ("u", (1, dh), isz), ("h0", state, 4),
+            ("w", seq, isz), ("u", (1, 1, dh), isz), ("h0", state, 4),
             ("out", seq, isz), ("s_out", state, 4),
         ),
-        scratch=(("S", (dh, dh), 4),),
     )

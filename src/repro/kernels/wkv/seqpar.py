@@ -31,6 +31,10 @@ would, while each shard's local gradient goes through the reverse
 elevator kernel (``bwd.py``) via the ``wkv_diff_summary`` custom VJP.
 Only segment summaries ever cross the axis, forward or backward
 (asserted on the jaxpr in ``tests/test_multidevice.py``).
+
+Windows that are not sequence-sharded still need a mesh placement when
+the kernel runs on more than one device: :func:`wkv_headshard` splits
+batch and heads, which share nothing.
 """
 
 from __future__ import annotations
@@ -41,13 +45,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import device_comm
 from repro.core.chunk_scan import DIAG_STATE, device_linear_scan_carry
-from repro.kernels.common import shard_map_norep
-from repro.kernels.wkv.ops import wkv_fused_summary
+from repro.kernels.wkv.ops import wkv_fused, wkv_fused_summary
 from repro.kernels.wkv.ref import wkv_entry_correction
 
-__all__ = ["wkv_seq_local", "wkv_seqshard"]
+__all__ = ["wkv_headshard", "wkv_seq_local", "wkv_seqshard"]
 
 
 def wkv_seq_local(
@@ -77,7 +79,7 @@ def wkv_seq_local(
     # masked psum moves one more O(Dh²) summary, never activations.
     s_exit = DIAG_STATE.scale(a_seg, s_in) + s0
     idx = jax.lax.axis_index(axis_name)
-    n = device_comm.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     s_out = jax.lax.psum(jnp.where(idx == n - 1, s_exit, 0.0), axis_name)
     return out, s_out
 
@@ -114,11 +116,44 @@ def wkv_seqshard(
     local = functools.partial(
         wkv_seq_local, axis_name=seq_axis, chunk=chunk, use_kernel=use_kernel
     )
-    fn = shard_map_norep(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, seq_spec, P(None, None),
                   state_spec),
         out_specs=(seq_spec, state_spec),
+        check_vma=False,
+    )
+    return fn(r, k, v, w, u, h0)
+
+
+def wkv_headshard(
+    r: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    w: jax.Array,
+    u: jax.Array,
+    h0: jax.Array,
+    *,
+    mesh,
+    batch_axis=None,
+    head_axis=None,
+    **kw,
+):
+    """:func:`~repro.kernels.wkv.ops.wkv_fused` (same arguments and
+    returns) with batch over ``batch_axis`` and heads over ``head_axis``.
+
+    Every (batch, head) pair is an independent recurrence, so each device
+    runs the fused kernel on its own block and nothing crosses devices.
+    The compiler cannot partition a Mosaic kernel by itself: under a
+    multi-device mesh the kernel path must run inside a ``shard_map``.
+    """
+    blk = P(batch_axis, head_axis, None, None)
+    fn = jax.shard_map(
+        functools.partial(wkv_fused, **kw),
+        mesh=mesh,
+        in_specs=(blk, blk, blk, blk, P(head_axis, None), blk),
+        out_specs=(blk, blk),
+        check_vma=False,
     )
     return fn(r, k, v, w, u, h0)

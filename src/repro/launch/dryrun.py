@@ -179,7 +179,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             + ma.temp_size_in_bytes
             - ma.alias_size_in_bytes,
         }
-        ca = R.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         result["cost"] = {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -12,6 +12,7 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e per-chip hardware constants (roofline terms).
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
@@ -21,12 +22,8 @@ ICI_LINKS = 4                   # 2D torus: 4 links per chip
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: 0.4.x has no axis_types kwarg (auto
-    mode is the only behavior there, which is what we ask for anyway)."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` in Auto axis mode (GSPMD picks the shardings the
+    rules leave open)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

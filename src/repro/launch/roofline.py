@@ -32,21 +32,14 @@ _COLLECTIVES = (
 # Result types preceding the op name, e.g.
 #   %x = bf16[16,128]{1,0} all-gather(...)
 #   %y = (f32[8], f32[16]) all-reduce-start(...)
+#   %z = (bf16[2,8]{1,0:T(2,128)(2,1)}, u32[]{:S(2)}) collective-permute-start(
+# TPU layouts nest one level of parentheses inside a tuple type.
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _OP_RE = re.compile(
-    r"=\s*(?:\([^)]*\)|\w+\[[\d,]*\](?:\{[^}]*\})?)\s*"
+    r"=\s*(?:\((?:[^()]|\([^()]*\))*\)|\w+\[[\d,]*\](?:\{[^}]*\})?)\s*"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\("
 )
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: 0.4.x
-    returns a one-dict-per-program list, newer jax a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
 
 
 def _shape_bytes(dtype: str, dims: str) -> int:

@@ -38,7 +38,7 @@ def _measure(arch, shape_name, cfg, *, multi_pod=False):
             arch, shape_name, multi_pod=multi_pod, cfg_override=cfg
         )
     compiled = lowered.compile()
-    ca = R.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     coll = R.parse_collective_bytes(compiled.as_text())
     out = {
         "flops": float(ca.get("flops", 0.0)),

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.model import model as M
 from repro.serve.engine import Request, ServeEngine
 
@@ -138,6 +139,7 @@ def main():
                          "dispatch indices (fires once; needs "
                          "--snapshot-every for handoff)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

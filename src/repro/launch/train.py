@@ -24,6 +24,7 @@ from repro.checkpoint import checkpoint as ckpt
 from repro.configs.registry import get_config
 from repro.data.pipeline import DataConfig, make_batch
 from repro.ft.watchdog import run_with_restarts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw
 from repro.train import step as train_mod
 
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

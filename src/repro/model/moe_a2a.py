@@ -134,14 +134,12 @@ def apply_moe_sharded(params, x: jax.Array, cfg):
         "w_up": P("model", None, None),
         "w_down": P("model", None, None),
     }
-    # Replication checking off (the a2a writes are deliberately uneven);
-    # shard_map_norep owns the check_rep/check_vma jax-version spelling.
-    from repro.kernels.common import shard_map_norep
-
-    f = shard_map_norep(
+    # Replication checking off: the a2a writes are deliberately uneven.
+    f = jax.shard_map(
         partial(apply_moe_a2a, cfg=cfg, axis_name="model"),
         mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )
     return f(params, x)

@@ -19,13 +19,15 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import on_tpu
 from repro.kernels.elevator_scan.ops import elevator_scan
 from repro.kernels.token_shift.ops import token_shift
 from repro.kernels.wkv.ops import wkv_fused
 from repro.kernels.wkv.ref import wkv_chunked_ref, wkv_sequential_ref
-from repro.kernels.wkv.seqpar import wkv_seqshard
+from repro.kernels.wkv.seqpar import wkv_headshard, wkv_seqshard
 from repro.model.layers import init_rmsnorm, rms_norm
 from repro.model.sharding import (
+    active_context,
     axes_size,
     constrain,
     gather_for_use,
@@ -169,6 +171,28 @@ def _rwkv_mix(x, x_prev, mu_row):
 _wkv_chunked = wkv_chunked_ref
 
 
+def _wkv_head_plan(b: int, h: int, use_kernel: bool | None):
+    """(mesh, batch_axes, head_axes) when the WKV kernel runs under a
+    multi-device sharding context, else None.
+
+    The compiler cannot partition a Mosaic kernel: on more than one
+    device the kernel runs per (batch, head) block inside a shard_map
+    (:func:`~repro.kernels.wkv.seqpar.wkv_headshard`), batch and heads
+    mapped as the active rules map them where they divide evenly.  The
+    jnp path partitions like any other op and needs no plan.
+    """
+    ctx = active_context()
+    kernel = on_tpu() if use_kernel is None else use_kernel
+    if ctx is None or not kernel or ctx[0].size == 1:
+        return None
+    mesh, rules = ctx
+
+    def fit(axes, n):
+        return axes if axes and n % axes_size(mesh, axes) == 0 else None
+
+    return mesh, fit(rules.get("batch"), b), fit(rules.get("act_heads"), h)
+
+
 def apply_rwkv_block(params, x: jax.Array, cfg, *, state: RecState | None = None,
                      chunk: int = 16, use_kernel: bool | None = None,
                      token_mask: jax.Array | None = None):
@@ -263,8 +287,7 @@ def apply_rwkv_block(params, x: jax.Array, cfg, *, state: RecState | None = None
             chunk=chunk, use_kernel=use_kernel,
         )
     else:
-        out, S = wkv_fused(
-            r_, k_, v_, w_, u, h0,
+        wkv_kw = dict(
             chunk=chunk,
             use_kernel=use_kernel,
             decode=state is not None,
@@ -272,6 +295,15 @@ def apply_rwkv_block(params, x: jax.Array, cfg, *, state: RecState | None = None
             # (T, chunk) each get their own chunk-adjustment warning.
             warn_scope=getattr(cfg, "name", None),
         )
+        head_plan = _wkv_head_plan(b, h, use_kernel)
+        if head_plan is None:
+            out, S = wkv_fused(r_, k_, v_, w_, u, h0, **wkv_kw)
+        else:
+            mesh, batch_ax, head_ax = head_plan
+            out, S = wkv_headshard(
+                r_, k_, v_, w_, u, h0,
+                mesh=mesh, batch_axis=batch_ax, head_axis=head_ax, **wkv_kw,
+            )
 
     out = out.swapaxes(1, 2).reshape(b, t, d).astype(x.dtype)
     out = rms_norm(params["out_norm"], out, cfg.norm_eps) * g

@@ -120,6 +120,13 @@ def axes_size(mesh: Mesh, axes) -> int:
     return n
 
 
+def active_context():
+    """(mesh, rules) of the active sharding context; None outside one."""
+    if _CTX.mesh is None or _CTX.rules is None:
+        return None
+    return _CTX.mesh, _CTX.rules
+
+
 def seq_shard_info():
     """(mesh, seq_axes, batch_axes) when the active rules map ``seq`` to a
     mesh axis (sequence-parallel mode, e.g. ``prefill_seq``); None

@@ -519,7 +519,8 @@ class ServeEngine:
     dispatches.
 
     ``mesh`` routes long prompts through the sequence-parallel prefill
-    rules (see :func:`make_cache_prefill_step`).
+    rules (see :func:`make_cache_prefill_step`); decode windows run under
+    the ``decode`` rules.
 
     ``serve(requests)`` is the continuous-batching scheduler on top of the
     same jitted pieces: per-request lengths, in-window sampling and EOS
@@ -618,7 +619,7 @@ class ServeEngine:
                     toks = jnp.concatenate([toks, cur], axis=1)
                 return toks, state, cur, pos
 
-            fn = jax.jit(win, donate_argnums=(1,))
+            fn = self._under_mesh(jax.jit(win, donate_argnums=(1,)), "decode")
             self._windows[(k, last)] = fn
         return fn
 
@@ -684,23 +685,32 @@ class ServeEngine:
                 return (state, lengths, counts, active, cur, tok0,
                         csum_in, csum_out)
 
-            fn = jax.jit(admit, donate_argnums=(1,))
-            if self.mesh is not None:
-                from repro.model.sharding import make_rules, sharding_context
-
-                mesh = self.mesh
-                rules = make_rules(
-                    mesh,
-                    "prefill_seq" if p >= SEQ_PREFILL_MIN_T else "prefill",
-                )
-                jitted = fn
-
-                def fn(*args):
-                    with mesh, sharding_context(mesh, rules):
-                        return jitted(*args)
-
+            fn = self._under_mesh(
+                jax.jit(admit, donate_argnums=(1,)),
+                "prefill_seq" if p >= SEQ_PREFILL_MIN_T else "prefill",
+            )
             self._admits[key] = fn
         return fn
+
+    def _under_mesh(self, fn, mode: str):
+        """``fn`` traced and run under the engine mesh's ``mode`` sharding
+        rules; ``fn`` itself without a mesh.
+
+        The rules are read at trace time, so every jit that runs on the
+        mesh needs them: on a TPU they are what places the Pallas kernels
+        inside a ``shard_map`` (the compiler cannot partition a kernel).
+        """
+        if self.mesh is None:
+            return fn
+        from repro.model.sharding import make_rules, sharding_context
+
+        mesh, rules = self.mesh, make_rules(self.mesh, mode)
+
+        def run(*args):
+            with mesh, sharding_context(mesh, rules):
+                return fn(*args)
+
+        return run
 
     # -- paged admission + prefix sharing --------------------------------
 
@@ -911,7 +921,7 @@ class ServeEngine:
                 return (state, cur, lengths, counts, active, quar, toks,
                         emits, csum_in, csum_out)
 
-            fn = jax.jit(win, donate_argnums=(1,))
+            fn = self._under_mesh(jax.jit(win, donate_argnums=(1,)), "decode")
             self._serve_windows[key] = fn
         return fn
 

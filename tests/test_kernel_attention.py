@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.kernels.local_attention.kernel import flash_attention_pallas
-from repro.launch.roofline import cost_analysis_dict
 from repro.kernels.local_attention.ref import attention_ref
 
 jax.config.update("jax_platform_name", "cpu")
@@ -150,7 +149,7 @@ class TestBlockwise:
 
             with unrolled_cost_mode():
                 compiled = jax.jit(f).lower(q, k, v).compile()
-                return cost_analysis_dict(compiled)["flops"]
+                return compiled.cost_analysis()["flops"]
 
         f_small = make(4096, 256)
         f_big = make(4096, 2048)

@@ -154,6 +154,17 @@ class TestWKVDispatch:
             with pytest.raises(ValueError, match="chunk must be >= 1"):
                 wkv_fused(*args, chunk=bad)
 
+    def test_tpu_kernel_chunk_must_tile(self):
+        # The compiled kernel's (chunk, Dh) blocks need chunk % 8 == 0 or
+        # chunk == T; a divisor that is neither raises instead of warning.
+        assert resolve_chunk(256, 64, tpu_kernel=True) == 64
+        assert resolve_chunk(17, 64, tpu_kernel=True) == 17
+        with pytest.raises(ValueError, match="tiling"):
+            resolve_chunk(20, 16, tpu_kernel=True)
+        wkv_ops._CHUNK_WARNED.clear()
+        with pytest.warns(UserWarning, match="does not divide"):
+            assert resolve_chunk(20, 16) == 10  # off the chip: warns only
+
     def test_ref_raises_on_indivisible(self):
         args = _wkv_inputs(1, 1, 20, 16, seed=9)
         with pytest.raises(ValueError):

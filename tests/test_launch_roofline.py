@@ -42,6 +42,21 @@ class TestCollectiveParsing:
              "collective-permute")
         )
 
+    def test_tpu_async_tuple_layouts(self):
+        # TPU modules emit async starts whose tuple result types carry
+        # tiled layouts with parentheses inside the braces.
+        txt = (
+            "  %cps = (bf16[2,1,2048]{2,1,0:T(2,128)(2,1)S(1)}, "
+            "bf16[2,1,2048]{2,1,0:T(2,128)(2,1)S(1)}, u32[]{:S(2)}, "
+            "u32[]{:S(2)}) collective-permute-start(%s), channel_id=2\n"
+            "  %cpd = bf16[2,1,2048]{2,1,0:T(2,128)(2,1)S(1)} "
+            "collective-permute-done(%cps)\n"
+        )
+        out = R.parse_collective_bytes(txt)
+        assert out["collective-permute"]["count"] == 1
+        assert out["collective-permute"]["bytes"] == 2 * (2 * 2048 * 2) + 8
+        assert out["all-gather"]["count"] == 0
+
     def test_real_compiled_module(self):
         # End-to-end: a psum over 1 device still emits an all-reduce line.
         def f(x):
@@ -153,7 +168,7 @@ class TestUnrollFlag:
             return f
 
         x = jnp.eye(64)
-        rolled = R.cost_analysis_dict(jax.jit(make()).lower(x).compile())["flops"]
+        rolled = jax.jit(make()).lower(x).compile().cost_analysis()["flops"]
         with unrolled_cost_mode():
-            unrolled = R.cost_analysis_dict(jax.jit(make()).lower(x).compile())["flops"]
+            unrolled = jax.jit(make()).lower(x).compile().cost_analysis()["flops"]
         assert unrolled > 4 * rolled  # 8 bodies vs 1 visited

@@ -20,11 +20,8 @@ SCRIPT = textwrap.dedent(
     from repro.model.moe_a2a import apply_moe_sharded
     from repro.model.sharding import init_mk, make_rules, sharding_context
 
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:  # jax 0.4.x: auto mode is the only (and default) behavior
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = dataclasses.replace(
         get_config("dbrx-132b").reduced(),
         d_model=32, d_ff=64, num_experts=8, num_experts_per_tok=2,

@@ -6,14 +6,15 @@ spawn 512), so these tests re-invoke python in a subprocess with
 (The tier-1 lane 2 in scripts/tier1.sh additionally runs the in-process
 device-gated tests with 8 fake devices.)
 
-Two scripts: SCRIPT exercises the core primitives; SCRIPT_WKV is the
+SCRIPT exercises the core primitives; SCRIPT_WKV is the
 sequence-parallel WKV acceptance suite — forward and gradient parity of
 ``wkv_seqshard`` against the single-device fused path on 8 devices, a
 jaxpr audit proving only O(Dh²) segment summaries (never token
 activations) cross the ``seq`` axis — via the shared
 ``repro.analysis.collectives`` pass, which replaced the walker that used
 to live inline here — the model-level ``prefill_seq`` dispatch and the
-serve-engine long-context prefill step.
+serve-engine long-context prefill step.  SCRIPT_WKV_HEADS checks the
+head-sharded WKV kernel path that decode windows take under a mesh.
 """
 
 import subprocess
@@ -30,7 +31,7 @@ SCRIPT = textwrap.dedent(
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core import (
@@ -354,6 +355,67 @@ SCRIPT_WKV = textwrap.dedent(
 )
 
 
+SCRIPT_WKV_HEADS = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import types
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.model import recurrent as rec
+    from repro.model.sharding import make_rules, sharding_context
+
+    # Decode windows of a mesh engine: the WKV kernel (interpreted here)
+    # runs per (batch, head) block inside a shard_map, which is what lets
+    # the TPU compiler take it.  Same numbers as the kernel on one device.
+    mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+    rules = make_rules(mesh, "decode")
+    b, d, t = 2, 256, 8
+    h = d // rec.RWKV_HEAD_DIM
+    with sharding_context(mesh, rules):
+        assert rec._wkv_head_plan(b, h, True) == (mesh, "data", "model")
+        assert rec._wkv_head_plan(b, h, False) is None
+        assert rec._wkv_head_plan(b, 3, True) == (mesh, "data", None)
+    assert rec._wkv_head_plan(b, h, True) is None
+
+    rng = np.random.default_rng(0)
+    mk = lambda shape, scale=0.1: jnp.asarray(
+        rng.standard_normal(shape).astype(np.float32) * scale)
+    params = {
+        "mu": mk((5, d)),
+        "w_r": mk((d, d)), "w_k": mk((d, d)),
+        "w_v": mk((d, d)), "w_g": mk((d, d)),
+        "w_decay_base": mk((d,)),
+        "w_decay_lora_a": mk((d, 64)),
+        "w_decay_lora_b": mk((64, d)),
+        "u_bonus": mk((d,)),
+        "w_o": mk((d, d)),
+        "out_norm": {"scale": jnp.ones((d,), jnp.float32)},
+    }
+    cfg = types.SimpleNamespace(fsdp_gather_weights=False, norm_eps=1e-6)
+    state = rec.RecState(h=mk((b, h, 64, 64), 1.0), conv=mk((b, 1, d), 1.0))
+    x = mk((b, t, d), scale=1.0)
+
+    def block(x_, st):
+        return rec.apply_rwkv_block(params, x_, cfg, state=st, use_kernel=True)
+
+    out1, st1 = jax.jit(block)(x, state)
+    with mesh, sharding_context(mesh, rules):
+        fn = jax.jit(block)
+        assert "shard_map" in str(jax.make_jaxpr(fn)(x, state))
+        out4, st4 = fn(x, state)
+    np.testing.assert_allclose(np.asarray(out4), np.asarray(out1),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(st4.h), np.asarray(st1.h),
+                               rtol=1e-5, atol=1e-5)
+    print("MULTIDEVICE_WKV_HEADS_OK")
+    """
+)
+
+
 def _run(script: str) -> subprocess.CompletedProcess:
     return subprocess.run(
         [sys.executable, "-c", script],
@@ -377,3 +439,9 @@ def test_multidevice_wkv_seqshard():
     res = _run(SCRIPT_WKV)
     assert res.returncode == 0, f"stdout:\n{res.stdout}\nstderr:\n{res.stderr}"
     assert "MULTIDEVICE_WKV_OK" in res.stdout
+
+
+def test_multidevice_wkv_headshard():
+    res = _run(SCRIPT_WKV_HEADS)
+    assert res.returncode == 0, f"stdout:\n{res.stdout}\nstderr:\n{res.stderr}"
+    assert "MULTIDEVICE_WKV_HEADS_OK" in res.stdout
