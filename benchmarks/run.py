@@ -2,68 +2,30 @@
 
   rodinia      -> paper Fig. 11 (speedup) + Fig. 12 (energy) analogs
   delta_cdf    -> paper Fig. 5 (ΔTID CDF)
-  kernel_bench -> per-kernel microbenchmarks (also written to
-                  BENCH_kernels.json at the repo root as the
-                  machine-readable perf baseline for future PRs)
   roofline     -> §Roofline table from the dry-run artifacts (if present)
 
-Prints ``name,us_per_call,derived`` CSV blocks.
+Prints ``name,us_per_call,derived`` CSV blocks.  These are CPU figures
+and models, not device measurements; the on-chip serving benchmark is
+``bench/`` (``BENCHMARK.json``).
 
 ``--smoke`` runs every section on tiny shapes with no timing loops — a
 CI-speed regression check for the bench *paths* (import errors, dispatch
-wiring, schema drift fail loudly instead of rotting until the next real
-bench run).  Smoke mode validates the row schema but never overwrites
-BENCH_kernels.json: tiny-shape timings are not a baseline.
+wiring fail loudly instead of rotting until the next real bench run).
 """
 
 from __future__ import annotations
 
 import argparse
-import json
-import pathlib
-
-BENCH_JSON = pathlib.Path(__file__).resolve().parents[1] / "BENCH_kernels.json"
-
-_ROW_KEYS = {"name", "us_per_call", "derived"}
-
-
-def _validate_rows(rows: list[dict]) -> None:
-    """Schema check (kernel_bench.v1): smoke mode's replacement for the
-    baseline write — drift fails tier-1 instead of corrupting the json."""
-    if not rows:
-        raise SystemExit("kernel_bench produced no rows")
-    for row in rows:
-        if set(row) != _ROW_KEYS:
-            raise SystemExit(f"kernel_bench row schema drift: {sorted(row)}")
-    if BENCH_JSON.exists():
-        baseline = json.loads(BENCH_JSON.read_text())
-        if baseline.get("schema") != "kernel_bench.v1":
-            raise SystemExit(
-                f"BENCH_kernels.json schema drift: {baseline.get('schema')}"
-            )
 
 
 def main(smoke: bool = False) -> None:
-    from benchmarks import delta_cdf, kernel_bench, rodinia
+    from benchmarks import delta_cdf, rodinia
 
     print("== rodinia (paper Fig. 11/12 analog) ==")
     rodinia.main(smoke=smoke)
     print()
     print("== delta CDF (paper Fig. 5 analog) ==")
     delta_cdf.main()
-    print()
-    print("== kernel microbenchmarks ==")
-    kernel_rows = kernel_bench.main(smoke=smoke)
-    if smoke:
-        _validate_rows(kernel_rows)
-        print("(smoke mode: schema validated, BENCH_kernels.json untouched)")
-    else:
-        BENCH_JSON.write_text(
-            json.dumps({"schema": "kernel_bench.v1", "rows": kernel_rows},
-                       indent=2)
-            + "\n"
-        )
-        print(f"(wrote {BENCH_JSON})")
     print()
     print("== roofline table (from dry-run artifacts) ==")
     try:
@@ -77,5 +39,5 @@ def main(smoke: bool = False) -> None:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny shapes, no timing loops, no baseline write")
+                    help="tiny shapes, no timing loops")
     main(smoke=ap.parse_args().smoke)

@@ -33,8 +33,8 @@ XLA_FLAGS="--xla_force_host_platform_device_count=8 ${XLA_FLAGS:-}" \
     python -m pytest -x -q tests/test_core_scan_comm.py tests/test_multidevice.py
 
 echo "== tier-1 lane 3: benchmark-path smoke (tiny shapes, no timing) =="
-# Catches bench-path regressions (import errors, dispatch wiring, row
-# schema drift) at CI speed; never rewrites BENCH_kernels.json.
+# Catches regressions in the paper-figure bench paths (import errors,
+# dispatch wiring) at CI speed.
 python -m benchmarks.run --smoke
 
 echo "== tier-1 lane 3b: continuous-serve smoke =="
@@ -64,9 +64,6 @@ python -m repro.launch.serve --arch gemma3-1b --smoke --continuous --paged \
     --requests 5 --slots 2 --prompt-len 6 --new-tokens 6 --max-len 128 \
     --decode-window 2 --prefix-len 40 --pool-pages 4 \
     --temperature 0.8 --top-k 16
-# The paged bench row (admission-cost ratio + footprint fields) must be
-# present in the committed benchmark results.
-grep -q '"name": "serve_paged"' BENCH_kernels.json
 
 echo "== tier-1 lane 3e: fleet chaos smoke (bitflip + replica kill) =="
 # Three engine replicas behind the fleet router.  The victim gets a
@@ -81,9 +78,6 @@ python -m repro.launch.serve --arch rwkv6-1.6b --smoke --continuous \
     --replicas 3 --requests 6 --slots 2 --prompt-len 10 --new-tokens 16 \
     --max-len 96 --decode-window 4 --snapshot-every 1 --checksum-every 2 \
     --chaos-bitflip-at 1 --chaos-replica-kill-at 3
-# The fleet bench row (goodput under replica kill + modeled drain) must
-# be present in the committed benchmark results.
-grep -q '"name": "serve_fleet"' BENCH_kernels.json
 
 echo "== tier-1 lane 3f: forced-interleaving drill (8 seeded schedules) =="
 # The dynamic complement to lane 0's static audit: a seeded scheduler

@@ -4,10 +4,10 @@ On TPU this runs the Pallas kernel; on CPU (this container) the kernel runs
 in interpret mode for validation, while speed-sensitive callers (models)
 get a jnp path via ``use_kernel=False`` / auto off-TPU.
 
-The jnp path is itself dispatched per backend (BENCH_kernels.json,
-``elevator_scan_jnp``): the log-depth ``associative_scan`` only wins where
-gather-heavy tree steps are cheap (accelerators); on CPU it was measured
-*slower* than the plain sequential reference (8.5ms vs 7.0ms at
+The jnp path is itself dispatched per backend (CPU wall-clock, measured
+when this dispatch was chosen): the log-depth ``associative_scan`` only
+wins where gather-heavy tree steps are cheap (accelerators); on CPU it was
+measured *slower* than the plain sequential reference (8.5ms vs 7.0ms at
 B=4,T=2048,D=256), and the two-level ``chunked_linear_scan`` schedule is
 slower still in XLA-CPU (9.3–12.8ms across chunk sizes and layouts — the
 intra-chunk tree pays the same strided-gather tax).  What wins on CPU is

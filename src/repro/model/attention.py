@@ -181,15 +181,17 @@ def apply_attention(
                 kv_cache.length + advance,
                 kv_cache.s_view, kv_cache.page_size,
             )
-            k_cache = _paged_gather(new_cache, pool_k)
-            v_cache = _paged_gather(new_cache, pool_v)
+            with jax.named_scope("model.attn.decode"):
+                k_cache = _paged_gather(new_cache, pool_k)
+                v_cache = _paged_gather(new_cache, pool_v)
         else:
             k_cache = _masked_insert(kv_cache.k, k, kv_cache.length, token_mask)
             v_cache = _masked_insert(kv_cache.v, v, kv_cache.length, token_mask)
             new_cache = KVCache(k_cache, v_cache, kv_cache.length + advance)
-        out = _decode_attention(
-            q, k_cache, v_cache, kv_cache.length, cfg, window=window
-        )
+        with jax.named_scope("model.attn.decode"):
+            out = _decode_attention(
+                q, k_cache, v_cache, kv_cache.length, cfg, window=window
+            )
     else:
         out = flash_attention(
             q, k, v,

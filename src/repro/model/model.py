@@ -509,21 +509,24 @@ def decode_step(params, cfg, state, tokens: jax.Array, lengths: jax.Array,
     positions = jnp.broadcast_to(
         lengths + jnp.arange(t, dtype=jnp.int32)[None, :], (b, t)
     ).astype(jnp.int32)
-    x = embed_tokens(params["tok"], tokens, cfg)
-    x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    with jax.named_scope("model.embed"):
+        x = embed_tokens(params["tok"], tokens, cfg)
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     x, new_state = tf.apply_stack(
         params["decoder"], x, cfg, positions=positions, causal=True,
         states=state, enc_out=enc_out, token_mask=token_mask,
     )
-    if last_only:
-        if token_mask is None:
-            x = x[:, -1:]
-        else:
-            # Per-request last valid position (clamped: an all-False row
-            # yields garbage logits the caller must ignore).
-            idx = jnp.clip(
-                jnp.sum(token_mask, axis=1, dtype=jnp.int32) - 1, 0, t - 1
-            )
-            x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    return logits_projection(params["tok"], x, cfg), new_state
+    with jax.named_scope("model.head"):
+        if last_only:
+            if token_mask is None:
+                x = x[:, -1:]
+            else:
+                # Per-request last valid position (clamped: an all-False
+                # row yields garbage logits the caller must ignore).
+                idx = jnp.clip(
+                    jnp.sum(token_mask, axis=1, dtype=jnp.int32) - 1, 0, t - 1
+                )
+                x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+        logits = logits_projection(params["tok"], x, cfg)
+    return logits, new_state

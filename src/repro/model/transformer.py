@@ -26,6 +26,10 @@ from repro.model.recurrent import RecState
 from repro.model.sharding import constrain
 
 ATTN_KINDS = ("attn", "local", "global")
+#: Trace scope of each block kind's sequence mixer (``jax.named_scope``).
+MIXER_SCOPES = {"attn": "model.mixer.attn", "local": "model.mixer.attn",
+                "global": "model.mixer.attn", "rec": "model.mixer.rglru",
+                "rwkv": "model.mixer.rwkv"}
 
 
 # --------------------------------------------------------------------------
@@ -62,21 +66,21 @@ def apply_block(
     ``token_mask`` (B, t) bool (decode only): masked tokens leave every
     state leaf untouched — KV slots unwritten, recurrent carries frozen.
     """
-    h = rms_norm(params["ln1"], x, cfg.norm_eps)
-    new_state = None
-    if kind in ATTN_KINDS:
-        out, new_state = attn_mod.apply_attention(
-            params["attn"], h, cfg, kind=kind, positions=positions,
-            causal=causal, kv_cache=state, token_mask=token_mask,
-        )
-    elif kind == "rec":
-        out, new_state = rec_mod.apply_rglru_block(
-            params["rec"], h, cfg, state=state, token_mask=token_mask)
-    elif kind == "rwkv":
-        out, new_state = rec_mod.apply_rwkv_block(
-            params["rwkv"], h, cfg, state=state, token_mask=token_mask)
-    else:
+    if kind not in MIXER_SCOPES:
         raise ValueError(kind)
+    h = rms_norm(params["ln1"], x, cfg.norm_eps)
+    with jax.named_scope(MIXER_SCOPES[kind]):
+        if kind in ATTN_KINDS:
+            out, new_state = attn_mod.apply_attention(
+                params["attn"], h, cfg, kind=kind, positions=positions,
+                causal=causal, kv_cache=state, token_mask=token_mask,
+            )
+        elif kind == "rec":
+            out, new_state = rec_mod.apply_rglru_block(
+                params["rec"], h, cfg, state=state, token_mask=token_mask)
+        else:
+            out, new_state = rec_mod.apply_rwkv_block(
+                params["rwkv"], h, cfg, state=state, token_mask=token_mask)
     x = x + out
 
     if enc_out is not None and "cross" in params:
@@ -87,15 +91,16 @@ def apply_block(
         x = x + out
 
     h = rms_norm(params["ln2"], x, cfg.norm_eps)
-    if cfg.num_experts:
-        if cfg.moe_impl == "a2a":
-            from repro.model.moe_a2a import apply_moe_sharded
+    with jax.named_scope("model.mlp"):
+        if cfg.num_experts:
+            if cfg.moe_impl == "a2a":
+                from repro.model.moe_a2a import apply_moe_sharded
 
-            out = apply_moe_sharded(params["ffn"], h, cfg)
+                out = apply_moe_sharded(params["ffn"], h, cfg)
+            else:
+                out = moe_mod.apply_moe(params["ffn"], h, cfg)
         else:
-            out = moe_mod.apply_moe(params["ffn"], h, cfg)
-    else:
-        out = apply_mlp(params["ffn"], h, cfg)
+            out = apply_mlp(params["ffn"], h, cfg)
     x = x + out
     return constrain(x, "batch", "seq", "act_embed"), new_state
 

@@ -31,6 +31,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.ft.watchdog import StepTimeout, StepWatchdog, StragglerDetector
 from repro.model import model as M
@@ -416,6 +417,13 @@ def _bucket32(length: int) -> int:
     return -(-max(int(length), 1) // 32) * 32
 
 
+#: Per-slot state checksum (:func:`repro.model.model.decode_state_checksum`)
+#: under the trace scope that the state checks' device time is read by.
+_state_checksum = jax.named_scope("serve.state_check")(
+    M.decode_state_checksum)
+
+
+@jax.named_scope("serve.reset_rows")
 def _reset_slot_rows(state, rows: jax.Array):
     """Zero the decode state of the slots marked in ``rows`` (B,) bool —
     and only those: neighbors' caches are untouched (a ``jnp.where`` per
@@ -480,6 +488,7 @@ def _reset_slot_rows(state, rows: jax.Array):
     )
 
 
+@jax.named_scope("serve.sample")
 def _sample_tokens(logits, base_key, req_ids, tok_idx, temperature, top_k):
     """Sample one token per slot from ``logits`` (B, V).
 
@@ -661,7 +670,7 @@ class ServeEngine:
                 # *before* any mutation — the host chains it against the
                 # previous dispatch's exit checksum to catch silent
                 # corruption of non-admitted rows between dispatches.
-                csum_in = M.decode_state_checksum(state)
+                csum_in = _state_checksum(state)
                 state = _reset_slot_rows(state, admit_row)
                 mask = admit_row[:, None] & (
                     jnp.arange(p, dtype=jnp.int32)[None, :] < plen[:, None]
@@ -681,7 +690,7 @@ class ServeEngine:
                     done |= tok0 == eos_id
                 active = jnp.where(admit_row, ~done, active)
                 cur = jnp.where(admit_row[:, None], tok0[:, None], cur)
-                csum_out = M.decode_state_checksum(state)
+                csum_out = _state_checksum(state)
                 return (state, lengths, counts, active, cur, tok0,
                         csum_in, csum_out)
 
@@ -800,7 +809,7 @@ class ServeEngine:
                       prefix_rows, tables, scrubs, rec_entries,
                       ring_contents, tok_idx, lengths, counts, budgets,
                       req_ids, active, cur, base_key):
-                csum_in = M.decode_state_checksum(state)
+                csum_in = _state_checksum(state)
                 state = _reset_slot_rows(state, admit_row)
                 state = paging.apply_admission(
                     state, roles, admit_row, prefix_rows, start_len,
@@ -824,7 +833,7 @@ class ServeEngine:
                     done |= tok0 == eos_id
                 active = jnp.where(admit_row, ~done, active)
                 cur = jnp.where(admit_row[:, None], tok0[:, None], cur)
-                csum_out = M.decode_state_checksum(state)
+                csum_out = _state_checksum(state)
                 return (state, lengths, counts, active, cur, tok0,
                         csum_in, csum_out)
 
@@ -877,7 +886,7 @@ class ServeEngine:
 
             def win(params, state, cur, lengths, counts, budgets, active,
                     req_ids, base_key):
-                csum_in = M.decode_state_checksum(state)
+                csum_in = _state_checksum(state)
                 quar0 = jnp.zeros_like(active)
 
                 def body(carry, _):
@@ -888,9 +897,10 @@ class ServeEngine:
                         max_len=max_len,
                     )
                     lg = logits[:, -1]
-                    finite = M.decode_state_finite(state) & jnp.all(
-                        jnp.isfinite(lg.astype(jnp.float32)), axis=-1
-                    )
+                    with jax.named_scope("serve.state_check"):
+                        finite = M.decode_state_finite(state) & jnp.all(
+                            jnp.isfinite(lg.astype(jnp.float32)), axis=-1
+                        )
                     bad = active & ~finite
                     quar = quar | bad
                     active = active & ~bad
@@ -917,7 +927,7 @@ class ServeEngine:
                         length=k,
                     )
                 )
-                csum_out = M.decode_state_checksum(state)
+                csum_out = _state_checksum(state)
                 return (state, cur, lengths, counts, active, quar, toks,
                         emits, csum_in, csum_out)
 
@@ -1338,6 +1348,8 @@ class ServeSession:
         self.external = external
         self._clock = clock
         self.closed = False
+        #: Scheduler iterations run (the ``serve.step`` span's number).
+        self.steps = 0
 
         reqs = [
             r if hasattr(r, "tokens") else Request(tokens=r)
@@ -1587,10 +1599,18 @@ class ServeSession:
         """One scheduler iteration: deadline sweep, admission (recoveries
         first), one decode window with quarantine / checksum / chaos
         bookkeeping.  Exactly the loop body :meth:`ServeEngine.serve`
-        always ran — extracted so a fleet can interleave replicas."""
-        self._sweep_deadlines()
-        self._admit()
-        self._decode_window()
+        always ran — extracted so a fleet can interleave replicas.
+
+        Under a profiler the iteration is a ``serve.step`` span holding
+        ``serve.admit`` and ``serve.window``, each split into
+        ``.prepare`` (host arrays, jit lookup), ``.dispatch``, ``.sync``
+        (device reads) and ``.bookkeep``; with no profiler running the
+        spans record nothing."""
+        with StepTraceAnnotation("serve.step", step_num=self.steps):
+            self._sweep_deadlines()
+            self._admit()
+            self._decode_window()
+        self.steps += 1
 
     def _sweep_deadlines(self):
         if not self.any_deadline:
@@ -1654,200 +1674,225 @@ class ServeSession:
             take.append(ri)
         if not take:
             return
-        # A recovery's "prompt" is the original prompt plus its accepted
-        # tokens; fresh requests have none.
-        used = free[: len(take)]
-        admit_np = np.zeros(b, bool)
-        plen_np = np.zeros(b, np.int32)
-        tokidx_np = np.zeros(b, np.int32)
-        bud_np = np.array(self.budgets)
-        rid_np = np.array(self.req_ids)
-        full = {
-            ri: np.concatenate([
-                self.prompts_np[ri],
-                np.asarray(self.outputs[ri], np.int32),
-            ])
-            for ri in take
-        }
-        if ctl is None:
-            p_b = _bucket32(max(full[ri].size for ri in take))
-            tok_np = np.zeros((b, p_b), np.int32)
-            for slot, ri in zip(used, take):
-                t_arr = full[ri]
-                tok_np[slot, : t_arr.size] = t_arr
-                admit_np[slot] = True
-                plen_np[slot] = t_arr.size
-                tokidx_np[slot] = len(self.outputs[ri])
-                bud_np[slot] = int(self.reqs[ri].max_new_tokens)
-                rid_np[slot] = ri
-                self.slot_req[slot] = ri
-            self.budgets = jnp.asarray(bud_np)
-            self.req_ids = jnp.asarray(rid_np)
-            fn = eng._admit_step(
-                p_b, self.temperature, self.top_k, self.eos_id)
-            args = (eng.params, self.state, jnp.asarray(tok_np),
-                    jnp.asarray(admit_np), jnp.asarray(plen_np),
-                    jnp.asarray(tokidx_np), self.lengths, self.counts,
-                    self.budgets, self.req_ids, self.active, self.cur,
-                    self.base_key)
-        else:
-            # Paged: only the suffix past each request's shared-prefix
-            # start is prefilled; the prefix rides in as copied state /
-            # shared pages.
-            p_b = _bucket32(max(
-                full[ri].size - self.start_of[ri] for ri in take))
-            tok_np = np.zeros((b, p_b), np.int32)
-            start_np = np.zeros(b, np.int32)
-            prefix_np = np.zeros(b, bool)
-            for slot, ri in zip(used, take):
-                t_arr = full[ri][self.start_of[ri]:]
-                tok_np[slot, : t_arr.size] = t_arr
-                admit_np[slot] = True
-                plen_np[slot] = t_arr.size
-                start_np[slot] = self.start_of[ri]
-                prefix_np[slot] = self.start_of[ri] > 0
-                tokidx_np[slot] = len(self.outputs[ri])
-                bud_np[slot] = int(self.reqs[ri].max_new_tokens)
-                rid_np[slot] = ri
-                self.slot_req[slot] = ri
-                if self.start_of[ri] > 0:
-                    self.stats["prefix_admissions"] += 1
-            self.budgets = jnp.asarray(bud_np)
-            self.req_ids = jnp.asarray(rid_np)
-            tables, scrubs = [], []
-            for i_node, g in enumerate(ctl.geoms):
-                t_rows = np.full((b, g.nl), -1, np.int32)
-                s_rows = np.full((b, g.nl), -1, np.int32)
-                for slot in used:
-                    t_rows[slot] = slot_alloc[slot][0][i_node]
-                    s_rows[slot] = slot_alloc[slot][1][i_node]
-                tables.append(jnp.asarray(t_rows))
-                scrubs.append(jnp.asarray(s_rows))
-            if group_pid is not None:
-                _, rec, kv = eng._prefix_entry(
-                    group_pid, self.insert_window)
-            else:
-                rec, kv = eng._null_entry(self.insert_window)
-            ring = [kv[i] for i, role in enumerate(ctl.roles)
-                    if role == "copy"]
-            fn = eng._admit_step_paged(
-                p_b, self.temperature, self.top_k, self.eos_id, ctl.roles)
-            args = (eng.params, self.state, jnp.asarray(tok_np),
-                    jnp.asarray(admit_np), jnp.asarray(plen_np),
-                    jnp.asarray(start_np), jnp.asarray(prefix_np),
-                    tables, scrubs, rec, ring, jnp.asarray(tokidx_np),
-                    self.lengths, self.counts, self.budgets, self.req_ids,
-                    self.active, self.cur, self.base_key)
-        (self.state, self.lengths, self.counts, self.active, self.cur,
-         tok0, entry_csum, exit_csum) = eng._dispatch(
-            "admit", fn, args,
-            chaos=self.chaos, watchdog=self.watchdog,
-            straggler=self.straggler, stats=self.stats,
-            max_retries=self.max_retries, backoff_s=self.backoff_s,
-            index=self.stats["decode_dispatches"],
-        )
-        tok0_np = np.asarray(tok0)
-        self.active_np = np.array(self.active)
-        if self.checksum_every > 0:
-            # Chain check for the rows this admission did NOT touch: their
-            # state is frozen through the jit, so a mismatch means the
-            # bits changed between dispatches.
-            self._chain_check(np.asarray(entry_csum), skip=admit_np,
-                              emits_np=None)
-            self._csum_base = np.asarray(exit_csum).copy()
-            self._csum_have = True
-        for slot, ri in zip(used, take):
-            self.outputs[ri].append(int(tok0_np[slot]))
-            if not self.active_np[slot]:
-                # Done at admission (budget 1 / instant EOS).
-                self._resolve(ri)
-                self._free_slot(slot)
-        self.stats["admissions"] += 1
+        with TraceAnnotation("serve.admit") as span:
+            with TraceAnnotation("serve.admit.prepare"):
+                # A recovery's "prompt" is the original prompt plus its
+                # accepted tokens; fresh requests have none.
+                used = free[: len(take)]
+                admit_np = np.zeros(b, bool)
+                plen_np = np.zeros(b, np.int32)
+                tokidx_np = np.zeros(b, np.int32)
+                bud_np = np.array(self.budgets)
+                rid_np = np.array(self.req_ids)
+                full = {
+                    ri: np.concatenate([
+                        self.prompts_np[ri],
+                        np.asarray(self.outputs[ri], np.int32),
+                    ])
+                    for ri in take
+                }
+                if ctl is None:
+                    p_b = _bucket32(max(full[ri].size for ri in take))
+                    tok_np = np.zeros((b, p_b), np.int32)
+                    for slot, ri in zip(used, take):
+                        t_arr = full[ri]
+                        tok_np[slot, : t_arr.size] = t_arr
+                        admit_np[slot] = True
+                        plen_np[slot] = t_arr.size
+                        tokidx_np[slot] = len(self.outputs[ri])
+                        bud_np[slot] = int(self.reqs[ri].max_new_tokens)
+                        rid_np[slot] = ri
+                        self.slot_req[slot] = ri
+                    self.budgets = jnp.asarray(bud_np)
+                    self.req_ids = jnp.asarray(rid_np)
+                    fn = eng._admit_step(
+                        p_b, self.temperature, self.top_k, self.eos_id)
+                    args = (eng.params, self.state, jnp.asarray(tok_np),
+                            jnp.asarray(admit_np), jnp.asarray(plen_np),
+                            jnp.asarray(tokidx_np), self.lengths,
+                            self.counts, self.budgets, self.req_ids,
+                            self.active, self.cur, self.base_key)
+                else:
+                    # Paged: only the suffix past each request's shared-
+                    # prefix start is prefilled; the prefix rides in as
+                    # copied state / shared pages.
+                    p_b = _bucket32(max(
+                        full[ri].size - self.start_of[ri] for ri in take))
+                    tok_np = np.zeros((b, p_b), np.int32)
+                    start_np = np.zeros(b, np.int32)
+                    prefix_np = np.zeros(b, bool)
+                    for slot, ri in zip(used, take):
+                        t_arr = full[ri][self.start_of[ri]:]
+                        tok_np[slot, : t_arr.size] = t_arr
+                        admit_np[slot] = True
+                        plen_np[slot] = t_arr.size
+                        start_np[slot] = self.start_of[ri]
+                        prefix_np[slot] = self.start_of[ri] > 0
+                        tokidx_np[slot] = len(self.outputs[ri])
+                        bud_np[slot] = int(self.reqs[ri].max_new_tokens)
+                        rid_np[slot] = ri
+                        self.slot_req[slot] = ri
+                        if self.start_of[ri] > 0:
+                            self.stats["prefix_admissions"] += 1
+                    self.budgets = jnp.asarray(bud_np)
+                    self.req_ids = jnp.asarray(rid_np)
+                    tables, scrubs = [], []
+                    for i_node, g in enumerate(ctl.geoms):
+                        t_rows = np.full((b, g.nl), -1, np.int32)
+                        s_rows = np.full((b, g.nl), -1, np.int32)
+                        for slot in used:
+                            t_rows[slot] = slot_alloc[slot][0][i_node]
+                            s_rows[slot] = slot_alloc[slot][1][i_node]
+                        tables.append(jnp.asarray(t_rows))
+                        scrubs.append(jnp.asarray(s_rows))
+                    if group_pid is not None:
+                        _, rec, kv = eng._prefix_entry(
+                            group_pid, self.insert_window)
+                    else:
+                        rec, kv = eng._null_entry(self.insert_window)
+                    ring = [kv[i] for i, role in enumerate(ctl.roles)
+                            if role == "copy"]
+                    fn = eng._admit_step_paged(
+                        p_b, self.temperature, self.top_k, self.eos_id,
+                        ctl.roles)
+                    args = (eng.params, self.state, jnp.asarray(tok_np),
+                            jnp.asarray(admit_np), jnp.asarray(plen_np),
+                            jnp.asarray(start_np), jnp.asarray(prefix_np),
+                            tables, scrubs, rec, ring,
+                            jnp.asarray(tokidx_np), self.lengths,
+                            self.counts, self.budgets, self.req_ids,
+                            self.active, self.cur, self.base_key)
+            span.set_metadata(rows=len(take), slots=b, bucket=p_b,
+                              prompt_tokens=int(plen_np.sum()))
+            with TraceAnnotation("serve.admit.dispatch"):
+                (self.state, self.lengths, self.counts, self.active,
+                 self.cur, tok0, entry_csum, exit_csum) = eng._dispatch(
+                    "admit", fn, args,
+                    chaos=self.chaos, watchdog=self.watchdog,
+                    straggler=self.straggler, stats=self.stats,
+                    max_retries=self.max_retries, backoff_s=self.backoff_s,
+                    index=self.stats["decode_dispatches"],
+                )
+            with TraceAnnotation("serve.admit.sync"):
+                tok0_np = np.asarray(tok0)
+                self.active_np = np.array(self.active)
+                if self.checksum_every > 0:
+                    entry_np = np.asarray(entry_csum)
+                    exit_np = np.asarray(exit_csum)
+            with TraceAnnotation("serve.admit.bookkeep"):
+                if self.checksum_every > 0:
+                    # Chain check for the rows this admission did NOT
+                    # touch: their state is frozen through the jit, so a
+                    # mismatch means the bits changed between dispatches.
+                    self._chain_check(entry_np, skip=admit_np, emits_np=None)
+                    self._csum_base = exit_np.copy()
+                    self._csum_have = True
+                for slot, ri in zip(used, take):
+                    self.outputs[ri].append(int(tok0_np[slot]))
+                    if not self.active_np[slot]:
+                        # Done at admission (budget 1 / instant EOS).
+                        self._resolve(ri)
+                        self._free_slot(slot)
+                self.stats["admissions"] += 1
 
     def _decode_window(self):
         if not self.active_np.any():
             return
         eng = self.eng
-        if self.chaos is not None:
-            self.state, _ = self.chaos.maybe_poison(
-                self.state, self.active_np, self.stats["decode_dispatches"],
-                self.slot_req)
-            self.state, _ = self.chaos.maybe_bitflip(
-                self.state, self.active_np, self.stats["decode_dispatches"],
-                self.slot_req)
-        fn = eng._serve_window(self.k_w, self.temperature, self.top_k,
-                               self.eos_id)
-        (self.state, self.cur, self.lengths, self.counts, self.active,
-         quar, toks, emits, entry_csum, exit_csum) = eng._dispatch(
-            "window", fn,
-            (eng.params, self.state, self.cur, self.lengths, self.counts,
-             self.budgets, self.active, self.req_ids, self.base_key),
-            chaos=self.chaos, watchdog=self.watchdog,
-            straggler=self.straggler, stats=self.stats,
-            max_retries=self.max_retries, backoff_s=self.backoff_s,
-            index=self.stats["decode_dispatches"],
-        )
-        toks_np = np.asarray(toks)
-        emits_np = np.asarray(emits)
-        for step_i in range(self.k_w):
-            for slot in np.nonzero(emits_np[step_i])[0]:
-                self.outputs[self.slot_req[slot]].append(
-                    int(toks_np[step_i, slot]))
-        prev_active = self.active_np
-        self.active_np = np.array(self.active)
-        quar_np = np.asarray(quar)
-        self.stats["decode_dispatches"] += 1
-        self.stats["slot_steps"] += self.k_w * self.b
-        corrupt_np = np.zeros(self.b, bool)
-        if self.checksum_every > 0:
-            # Checksum chain: this window's entry checksum must equal the
-            # last dispatch's exit checksum.  In-jit quarantined slots are
-            # skipped here — the NaN path already recovers them.
-            corrupt_np = self._chain_check(
-                np.asarray(entry_csum), skip=quar_np, emits_np=emits_np)
-            self._csum_base = np.asarray(exit_csum).copy()
-            self._csum_have = True
-        # Quarantined slots: queue the victim for re-prefill recovery
-        # from its accepted prefix.
-        for slot in np.nonzero(quar_np)[0]:
-            ri = self.slot_req[slot]
-            self.stats["quarantines"] += 1
-            self.stats["recoveries"] += 1
-            self.recoveries[ri] += 1
-            self.recover_q.append(ri)
-            self._free_slot(slot)
-        # Completions: active before, inactive after, not quarantined and
-        # not checksum-corrupt (a corrupt slot's "completion" was computed
-        # from bad bits — it re-queues instead).
-        for slot in np.nonzero(
-                prev_active & ~self.active_np & ~quar_np & ~corrupt_np)[0]:
-            ri = self.slot_req[slot]
-            if ri >= 0:
-                self._resolve(ri)
-                self._free_slot(slot)
-        if self.chaos is not None:
-            slot = self.chaos.maybe_drop_request(
-                self.active_np, self.stats["decode_dispatches"],
-                self.slot_req)
-            if slot is not None:
-                ri = self.slot_req[slot]
-                self.outcomes[ri] = "dropped"
-                self.stats["req_drops"] += 1
-                self.newly_done.append(ri)
-                self.active_np[slot] = False
-                self._free_slot(slot)
-                self.active = jnp.asarray(self.active_np)
-        if self.checksum_every > 0:
-            self._since_spot += 1
-            if self._since_spot >= self.checksum_every:
-                self._spot_check()
-        if (self.snapshot_every > 0 and self.snapshot_dir is not None
-                and self.stats["decode_dispatches"]
-                % self.snapshot_every == 0):
-            self.snapshot_now()
-        if self.chaos is not None:
-            self.chaos.check_preempt(self.stats["decode_dispatches"])
-            self.chaos.check_replica_kill(self.stats["decode_dispatches"])
+        with TraceAnnotation("serve.window",
+                             active=int(self.active_np.sum()), k=self.k_w):
+            with TraceAnnotation("serve.window.prepare"):
+                if self.chaos is not None:
+                    self.state, _ = self.chaos.maybe_poison(
+                        self.state, self.active_np,
+                        self.stats["decode_dispatches"], self.slot_req)
+                    self.state, _ = self.chaos.maybe_bitflip(
+                        self.state, self.active_np,
+                        self.stats["decode_dispatches"], self.slot_req)
+                fn = eng._serve_window(self.k_w, self.temperature,
+                                       self.top_k, self.eos_id)
+            with TraceAnnotation("serve.window.dispatch"):
+                (self.state, self.cur, self.lengths, self.counts,
+                 self.active, quar, toks, emits, entry_csum,
+                 exit_csum) = eng._dispatch(
+                    "window", fn,
+                    (eng.params, self.state, self.cur, self.lengths,
+                     self.counts, self.budgets, self.active, self.req_ids,
+                     self.base_key),
+                    chaos=self.chaos, watchdog=self.watchdog,
+                    straggler=self.straggler, stats=self.stats,
+                    max_retries=self.max_retries, backoff_s=self.backoff_s,
+                    index=self.stats["decode_dispatches"],
+                )
+            with TraceAnnotation("serve.window.sync"):
+                toks_np = np.asarray(toks)
+                emits_np = np.asarray(emits)
+                active_np = np.array(self.active)
+                quar_np = np.asarray(quar)
+                if self.checksum_every > 0:
+                    entry_np = np.asarray(entry_csum)
+                    exit_np = np.asarray(exit_csum)
+            with TraceAnnotation("serve.window.bookkeep"):
+                for step_i in range(self.k_w):
+                    for slot in np.nonzero(emits_np[step_i])[0]:
+                        self.outputs[self.slot_req[slot]].append(
+                            int(toks_np[step_i, slot]))
+                prev_active, self.active_np = self.active_np, active_np
+                self.stats["decode_dispatches"] += 1
+                self.stats["slot_steps"] += self.k_w * self.b
+                corrupt_np = np.zeros(self.b, bool)
+                if self.checksum_every > 0:
+                    # Checksum chain: this window's entry checksum must
+                    # equal the last dispatch's exit checksum.  In-jit
+                    # quarantined slots are skipped here — the NaN path
+                    # already recovers them.
+                    corrupt_np = self._chain_check(
+                        entry_np, skip=quar_np, emits_np=emits_np)
+                    self._csum_base = exit_np.copy()
+                    self._csum_have = True
+                # Quarantined slots: queue the victim for re-prefill
+                # recovery from its accepted prefix.
+                for slot in np.nonzero(quar_np)[0]:
+                    ri = self.slot_req[slot]
+                    self.stats["quarantines"] += 1
+                    self.stats["recoveries"] += 1
+                    self.recoveries[ri] += 1
+                    self.recover_q.append(ri)
+                    self._free_slot(slot)
+                # Completions: active before, inactive after, not
+                # quarantined and not checksum-corrupt (a corrupt slot's
+                # "completion" was computed from bad bits — it re-queues
+                # instead).
+                for slot in np.nonzero(prev_active & ~self.active_np
+                                       & ~quar_np & ~corrupt_np)[0]:
+                    ri = self.slot_req[slot]
+                    if ri >= 0:
+                        self._resolve(ri)
+                        self._free_slot(slot)
+                if self.chaos is not None:
+                    slot = self.chaos.maybe_drop_request(
+                        self.active_np, self.stats["decode_dispatches"],
+                        self.slot_req)
+                    if slot is not None:
+                        ri = self.slot_req[slot]
+                        self.outcomes[ri] = "dropped"
+                        self.stats["req_drops"] += 1
+                        self.newly_done.append(ri)
+                        self.active_np[slot] = False
+                        self._free_slot(slot)
+                        self.active = jnp.asarray(self.active_np)
+                if self.checksum_every > 0:
+                    self._since_spot += 1
+                    if self._since_spot >= self.checksum_every:
+                        self._spot_check()
+                if (self.snapshot_every > 0 and self.snapshot_dir is not None
+                        and self.stats["decode_dispatches"]
+                        % self.snapshot_every == 0):
+                    self.snapshot_now()
+                if self.chaos is not None:
+                    self.chaos.check_preempt(self.stats["decode_dispatches"])
+                    self.chaos.check_replica_kill(
+                        self.stats["decode_dispatches"])
 
     # -- silent-corruption detection -------------------------------------
 
