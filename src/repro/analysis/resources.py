@@ -41,6 +41,7 @@ def _load_specs():
     """Import every kernel ops module (registration side effects), then
     return the registry."""
     import repro.kernels.elevator_scan.ops  # noqa: F401
+    import repro.kernels.kv_insert.ops  # noqa: F401
     import repro.kernels.local_attention.ops  # noqa: F401
     import repro.kernels.token_shift.ops  # noqa: F401
     import repro.kernels.wkv.ops  # noqa: F401

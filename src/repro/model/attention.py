@@ -16,9 +16,10 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.kv_insert.ops import LANES, write_rows
 from repro.kernels.local_attention.ops import flash_attention
 from repro.model.layers import apply_rope, init_rmsnorm, rms_norm
-from repro.model.sharding import constrain, gather_for_use
+from repro.model.sharding import active_context, constrain, gather_for_use
 
 
 class KVCache(NamedTuple):
@@ -27,6 +28,30 @@ class KVCache(NamedTuple):
     length: jax.Array     # (B,) int32 — tokens filled per request (a scalar
     #                       broadcasts: every request at the same position,
     #                       the lockstep special case)
+
+
+class KVLayer(NamedTuple):
+    """Layer ``layer`` of a stacked dense cache, as one decode step's
+    attention layer sees it: the whole ``(L, B, Hkv, S, Dh)`` buffers, so
+    that the insert writes only its rows of them, in place
+    (``transformer.apply_stack`` carries them through its layer scan)."""
+
+    k: jax.Array          # (L, B, Hkv, S, Dh)
+    v: jax.Array          # (L, B, Hkv, S, Dh)
+    length: jax.Array     # (B,) int32 — this layer's fill per request
+    layer: jax.Array | int   # () int32
+
+
+def writes_in_place(cache) -> bool:
+    """A dense cache on one device whose sequence axis is a whole number
+    of 128-lane blocks takes the in-place insert
+    (:func:`_insert_in_place`).  Any other cache keeps
+    :func:`_masked_insert`'s whole-cache rewrite: under a mesh that is
+    what keeps each shard of a sequence-sharded cache writing only its
+    own slots."""
+    ctx = active_context()
+    return (isinstance(cache, KVCache) and cache.k.shape[-2] % LANES == 0
+            and (ctx is None or ctx[0].size == 1))
 
 
 #: Physical page 0 of every page pool is the *null page*: never allocated,
@@ -184,6 +209,17 @@ def apply_attention(
             with jax.named_scope("model.attn.decode"):
                 k_cache = _paged_gather(new_cache, pool_k)
                 v_cache = _paged_gather(new_cache, pool_v)
+        elif isinstance(kv_cache, KVLayer) or writes_in_place(kv_cache):
+            # Write only the new rows, into the stacked buffers in place
+            # (an unstacked cache is a stack of one).
+            stacked = isinstance(kv_cache, KVLayer)
+            view = kv_cache if stacked else KVLayer(
+                kv_cache.k[None], kv_cache.v[None], kv_cache.length, 0)
+            k_all, v_all = _insert_in_place(view, k, v, token_mask)
+            k_cache, v_cache = k_all[view.layer], v_all[view.layer]
+            length = kv_cache.length + advance
+            new_cache = (KVLayer(k_all, v_all, length, view.layer) if stacked
+                         else KVCache(k_cache, v_cache, length))
         else:
             k_cache = _masked_insert(kv_cache.k, k, kv_cache.length, token_mask)
             v_cache = _masked_insert(kv_cache.v, v, kv_cache.length, token_mask)
@@ -213,6 +249,18 @@ def _lengths_2d(length: jax.Array, b: int) -> jax.Array:
     return jnp.broadcast_to(jnp.reshape(length, (-1, 1)), (b, 1))
 
 
+def _check_window_fits(t: int, s: int):
+    """A window wider than the whole ring can never be represented —
+    static shapes, so reject at trace time.  (Windows that *fit* the ring
+    but exceed the state's insert_window contract are rejected by
+    model.decode_step, which knows the layer kinds and max_len.)"""
+    if t > s:
+        raise ValueError(
+            f"decode window of {t} tokens exceeds cache size {s}; build the "
+            f"state with init_decode_state(insert_window >= {t})"
+        )
+
+
 def _masked_insert(cache: jax.Array, new: jax.Array, length: jax.Array,
                    token_mask: jax.Array | None = None):
     """Insert `new` (B,H,t,D) at absolute positions length..length+t-1
@@ -231,15 +279,7 @@ def _masked_insert(cache: jax.Array, new: jax.Array, length: jax.Array,
     b = cache.shape[0]
     s = cache.shape[2]
     t = new.shape[2]
-    if t > s:
-        # A window wider than the whole ring can never be represented —
-        # static shapes, so reject at trace time.  (Windows that *fit* the
-        # ring but exceed the state's insert_window contract are rejected
-        # by model.decode_step, which knows the layer kinds and max_len.)
-        raise ValueError(
-            f"decode window of {t} tokens exceeds cache size {s}; build the "
-            f"state with init_decode_state(insert_window >= {t})"
-        )
+    _check_window_fits(t, s)
     idx = jnp.arange(s, dtype=jnp.int32)
     # The window token landing on each slot (ring: slot = pos mod S);
     # t <= S guarantees at most one writer per slot.
@@ -259,6 +299,63 @@ def _masked_insert(cache: jax.Array, new: jax.Array, length: jax.Array,
         axis=2,
     )
     return jnp.where(sel[:, None, :, None], gathered, cache)
+
+
+def _insert_in_place(view: KVLayer, k_new: jax.Array, v_new: jax.Array,
+                     token_mask: jax.Array | None = None):
+    """:func:`_masked_insert` of ``k_new``/``v_new`` (B, Hkv, t, Dh) into
+    layer ``view.layer`` of the stacked buffers, writing only what the
+    window adds.  Returns the stacked ``(k, v)``.
+
+    One token (a decode step): each request whose token is real writes
+    its ``(Hkv, Dh)`` row at ring slot ``length mod S``
+    (:func:`repro.kernels.kv_insert.ops.write_rows`); a masked request
+    writes nothing.
+
+    A window of ``t`` tokens in which every request that writes starts
+    at position 0 (an admission: the admitted rows were just reset, every
+    other row is masked): positions ``[0, t)`` of every row are written,
+    a masked token's with what it held, in one ``dynamic_update_slice``
+    per buffer — sized by ``t``, and traced the same for every ``t``.
+    Any other window rewrites the layer through :func:`_masked_insert`.
+    """
+    b, _, t, _ = k_new.shape
+    s = view.k.shape[-2]
+    _check_window_fits(t, s)
+    length = _lengths_2d(view.length, b)[:, 0]
+    if t == 1:
+        write = (jnp.ones((b,), bool) if token_mask is None
+                 else token_mask[:, 0])
+        slot = jnp.where(write, jnp.mod(length, s), s)
+        with jax.named_scope("model.attn.insert"):
+            return write_rows(view.k, view.v, k_new[:, :, 0], v_new[:, :, 0],
+                              slot, view.layer)
+    mask = (jnp.ones((b, t), bool) if token_mask is None else token_mask)
+    from_zero = jnp.all((length == 0) | ~jnp.any(mask, axis=1))
+    news = (k_new, v_new)
+
+    def prefix(caches):
+        start = (view.layer, 0, 0, 0, 0)
+        out = []
+        with jax.named_scope("model.attn.insert"):
+            for cache, new in zip(caches, news):
+                old = jax.lax.dynamic_slice(cache, start, (1,) + new.shape)
+                new = jnp.where(mask[None, :, None, :, None],
+                                new[None].astype(cache.dtype), old)
+                out.append(jax.lax.dynamic_update_slice(cache, new, start))
+        return tuple(out)
+
+    def whole(caches):
+        out = []
+        for cache, new in zip(caches, news):
+            layer = jax.lax.dynamic_index_in_dim(cache, view.layer,
+                                                 keepdims=False)
+            layer = _masked_insert(layer, new, view.length, token_mask)
+            out.append(jax.lax.dynamic_update_index_in_dim(
+                cache, layer, view.layer, 0))
+        return tuple(out)
+
+    return jax.lax.cond(from_zero, prefix, whole, (view.k, view.v))
 
 
 def _paged_gather(cache: PagedKVCache, pool: jax.Array) -> jax.Array:

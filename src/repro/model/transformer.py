@@ -199,7 +199,7 @@ def apply_stack(
             x, _ = jax.lax.scan(
                 scan_body, x, stack_params["scanned"], unroll=scan_unroll()
             )
-        else:
+        elif not any(map(attn_mod.writes_in_place, states["scanned"])):
             def scan_body(carry, inputs):
                 period_params, period_states = inputs
                 y, ns = period_fn(carry, period_params, period_states)
@@ -208,6 +208,10 @@ def apply_stack(
                 scan_body, x, (stack_params["scanned"], states["scanned"]),
                 unroll=scan_unroll(),
             )
+        else:
+            x, new_scan_states = _scan_carrying_kv(
+                period_fn, x, stack_params["scanned"], states["scanned"],
+                n_periods)
 
     new_rem_states = []
     for i, (sub_params, kind) in enumerate(zip(stack_params["remainder"], remainder)):
@@ -221,6 +225,42 @@ def apply_stack(
     if states is None:
         return x, None
     return x, {"scanned": new_scan_states, "remainder": new_rem_states}
+
+
+def _scan_carrying_kv(period_fn, x, scanned_params, scanned_states,
+                      n_periods: int):
+    """The layer scan of a decode step whose dense KV caches take the
+    in-place insert (:func:`repro.model.attention.writes_in_place`).
+
+    Scanned as ``xs`` and rebuilt as ``ys``, each layer's cache would be
+    copied whole at every step, however little the insert wrote.  So the
+    stacked K and V buffers ride the carry instead: each attention layer
+    gets them with its index (a :class:`repro.model.attention.KVLayer`)
+    and writes its rows of them in place.  Their lengths, and every other
+    state, stay ``xs``/``ys``.
+    """
+    carried = [attn_mod.writes_in_place(s) for s in scanned_states]
+    xs_states = [s.length if c else s
+                 for s, c in zip(scanned_states, carried)]
+    kv0 = [(s.k, s.v) if c else None for s, c in zip(scanned_states, carried)]
+
+    def scan_body(carry, inputs):
+        y, kv = carry
+        period_params, period_states, layer = inputs
+        views = [attn_mod.KVLayer(*kv[j], st, layer) if c else st
+                 for j, (st, c) in enumerate(zip(period_states, carried))]
+        y, ns = period_fn(y, period_params, views)
+        kv = [(n.k, n.v) if c else None for n, c in zip(ns, carried)]
+        ys = [n.length if c else n for n, c in zip(ns, carried)]
+        return (y, kv), ys
+
+    (x, kv), ys = jax.lax.scan(
+        scan_body, (x, kv0),
+        (scanned_params, xs_states, jnp.arange(n_periods, dtype=jnp.int32)),
+        unroll=scan_unroll(),
+    )
+    return x, [KVCache(*kv[j], n) if c else n
+               for j, (n, c) in enumerate(zip(ys, carried))]
 
 
 def _remat_policy(cfg):

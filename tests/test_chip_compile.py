@@ -9,7 +9,10 @@ the compiled program holds the kernel (``tpu_custom_call``).
 Shapes are the published widths the serve and train paths use:
 ``rwkv6-1.6b`` WKV (H 32, Dh 64, bf16), ``recurrentgemma-2b`` RG-LRU and
 temporal conv (d_rnn 2560), ``gemma3-1b`` attention (4 x 256 heads, one
-KV head, window 1024).
+KV head, window 1024), ``qwen2-0.5b`` KV row insert (24 layers, 32
+slots, 2 KV heads of 64, 2048 positions).  The ``qwen2-0.5b`` serve
+window and admit compile whole for one chip, the row kernel in the
+window, both with their decode state aliased in place.
 
 The mesh cases compile the serve engine's jits for all four chips of the
 topology: ``rwkv6-1.6b`` at its widths with the depth cut to two layers,
@@ -32,12 +35,14 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+import repro.kernels.kv_insert.ops as kv_ops
 import repro.kernels.wkv.ops as wkv_ops
 import repro.model.recurrent as rec
 from repro.configs.registry import get_config
 
 from repro.kernels.elevator_scan.decode import elevator_decode_window_pallas
 from repro.kernels.elevator_scan.kernel import elevator_scan_pallas
+from repro.kernels.kv_insert.kernel import kv_row_insert
 from repro.kernels.local_attention.kernel import flash_attention_pallas
 from repro.kernels.token_shift.kernel import token_shift_pallas
 from repro.kernels.wkv.bwd import wkv_pallas_bwd
@@ -50,6 +55,7 @@ from repro.kernels.wkv.kernel import (
 )
 from repro.launch.roofline import parse_collective_bytes
 from repro.model import model as M
+from repro.serve import engine as serve_engine
 from repro.serve.engine import ServeEngine
 
 B, T = 4, 2048
@@ -133,6 +139,13 @@ def _flash(s):
             (s((B, 4, T, 256), BF16), kv, kv))
 
 
+def _kv_row(s):
+    cache = s((24, 32, 2, 64, 2048), BF16)   # (L, B, Hkv, Dh, S)
+    row = s((32, 2, 64), BF16)
+    return (jax.jit(kv_row_insert, donate_argnums=(0, 1)),
+            (cache, cache, row, row, s((32,), jnp.int32), s((), jnp.int32)))
+
+
 #: name -> builder(sds) -> (jitted fn, abstract args).  ``sds(shape,
 #: dtype)`` places a ShapeDtypeStruct on the described chip.
 CASES = {
@@ -157,6 +170,7 @@ CASES = {
     "token_shift.t2048": lambda s: _token_shift(s, T),
     "token_shift.t4": lambda s: _token_shift(s, 4),
     "local_attention.flash": _flash,
+    "kv_insert.row": _kv_row,
 }
 
 
@@ -217,3 +231,27 @@ def test_mesh_serve_compiles_for_four_v5e(jit, four_chips, tpu_dispatch,
         coll = parse_collective_bytes(text)
         assert coll["collective-permute"]["count"], coll
         assert not coll["all-gather"]["count"], coll
+
+
+@pytest.mark.parametrize("jit", ["serve.serve_window", "serve.admit"])
+def test_dense_serve_programs_compile_with_the_row_insert(
+        jit, one_chip, monkeypatch, no_compile_cache):
+    """The ``qwen2-0.5b`` window and admit at their widths for one chip:
+    the window holds the row kernel, and the donation pass finds both
+    aliasing their decode state."""
+    from repro.analysis.donation import check_entry
+
+    monkeypatch.setattr(kv_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(kv_ops, "interpret_default", lambda: False)
+    cfg = get_config("qwen2-0.5b")
+    entry = {e.name: e for e in serve_engine.audit_jit_entrypoints(
+        cfg, batch=32, max_len=2048, decode_window=16, prompt=128)}[jit]
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        entry.args)
+    entry = dataclasses.replace(entry, args=args)
+    findings = check_entry(entry)
+    assert [f.severity.name for f in findings] == ["INFO"], findings
+    if jit == "serve.serve_window":
+        text = entry.fn.lower(*args).compile().as_text()
+        assert "kv_row_insert" in text, "no row kernel in the window"

@@ -69,6 +69,24 @@ def test_reset_rows_only_in_admits(lowered):
     assert not _scopes_in(texts["serve.serve_window"], ["serve.reset_rows"])
 
 
+@pytest.mark.parametrize("max_len,dense_insert", [(128, True), (64, False)])
+def test_insert_scope_marks_the_in_place_write(max_len, dense_insert):
+    """``model.attn.insert`` is the in-place KV write of the dense admit
+    and window: there where the cache's positions are a whole number of
+    128 lanes, absent where the whole-cache rewrite stands in, in the
+    paged programs, and in a model without a KV cache."""
+    for arch in sorted(MIXER):
+        cfg = get_config(arch).reduced()
+        for e in E.audit_jit_entrypoints(cfg, max_len=max_len):
+            if e.name not in PROGRAMS:
+                continue
+            text = e.fn.lower(*e.args).as_text(debug_info=True)
+            want = (dense_insert and arch == "qwen2-0.5b"
+                    and not e.name.startswith("serve.paged"))
+            assert bool(_scopes_in(text, ["model.attn.insert"])) == want, (
+                arch, e.name)
+
+
 # --------------------------------------------------------------------------
 # Host spans
 # --------------------------------------------------------------------------
