@@ -33,6 +33,9 @@ class ArchConfig:
     pattern: tuple[str, ...] = ("attn",)  # repeating layer pattern, e.g.
     #   gemma3: ("local",)*5 + ("global",)  recurrentgemma: ("rec","rec","attn")
     attn_logit_softcap: float | None = None
+    attention_multiplier: float | None = None  # score scale (default
+    #   1/sqrt(head_dim)); granite: a fixed 0.015625
+    rope: bool = True                    # False: no position rotation (NoPE)
 
     # --- mlp ----------------------------------------------------------------
     mlp_type: str = "swiglu"             # swiglu | geglu | relu2
@@ -48,6 +51,10 @@ class ArchConfig:
     conv_width: int = 4                  # temporal conv in recurrent blocks
     d_rnn: int | None = None             # recurrence width (default d_model)
     rwkv: bool = False                   # RWKV6 blocks ("rwkv" kind)
+    mamba_heads: int = 0                 # Mamba-2 SSD blocks ("mamba" kind):
+    mamba_head_dim: int = 64             #   heads x head_dim = d_inner, one
+    mamba_d_state: int = 128             #   (head_dim, d_state) state a head,
+    #   one group: B and C shared by every head
 
     # --- encoder-decoder ----------------------------------------------------
     encoder_layers: int = 0              # >0 => enc-dec; num_layers = decoder
@@ -59,6 +66,12 @@ class ArchConfig:
     sub_quadratic: bool = False          # can run long_500k
     frontend: str | None = None          # 'vision' | 'audio' stub embeddings
     dtype: str = "bfloat16"
+    # muP multipliers (granite): the embedding scale (default sqrt(d_model)),
+    # the factor on both residual branches of every block, and the divisor
+    # of the logits.
+    embedding_multiplier: float | None = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # --- distribution defaults (overridable per run) ------------------------
     remat: str = "full"                  # none | dots | full
@@ -69,6 +82,9 @@ class ArchConfig:
     fsdp_gather_weights: bool = False    # explicitly all-gather FSDP-
     #   sharded weights at use (ZeRO-3 weight gathering) instead of
     #   letting GSPMD all-reduce partial activations (perf variant)
+    admit_rows: int = 0                  # slots one serve admission
+    #   prefills, the admitted ones and masked fillers (0: every slot);
+    #   more waiting requests wait for the next scheduler step
     head_pad: int = 0                    # zero-capacity extra q heads so
     #   (num_heads + head_pad) divides the TP width (perf variant)
     #   axis too (for head counts that don't divide the TP width)
@@ -90,6 +106,21 @@ class ArchConfig:
         padded logits are masked to -inf so semantics are unchanged.
         """
         return -(-self.vocab_size // 128) * 128
+
+    @property
+    def embed_scale(self) -> float:
+        if self.embedding_multiplier is None:
+            return self.d_model ** 0.5
+        return self.embedding_multiplier
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the causal conv: x, then B and C."""
+        return self.mamba_d_inner + 2 * self.mamba_d_state
 
     @property
     def layer_kinds(self) -> tuple[str, ...]:
@@ -119,13 +150,18 @@ class ArchConfig:
             rec = 2 * d * dr + dr * d + self.conv_width * dr + 3 * dr
         if self.rwkv:
             rec = 6 * d * d
+        if self.mamba_heads:
+            di, nh = self.mamba_d_inner, self.mamba_heads
+            cd = self.mamba_conv_dim
+            rec = (d * (di + cd + nh) + self.conv_width * cd + cd + 3 * nh
+                   + di + di * d)
         total = 0
         for kind in self.layer_kinds:
             if kind in ("attn", "local", "global"):
                 total += attn + mlp
             elif kind == "rec":
                 total += rec + mlp
-            elif kind == "rwkv":
+            elif kind in ("rwkv", "mamba"):
                 total += rec + mlp
         if self.is_enc_dec:
             # encoder self-attn + mlp, decoder already counted + cross-attn
@@ -151,7 +187,9 @@ class ArchConfig:
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            num_layers=min(self.num_layers, len(self.pattern) * 2),
+            # Two periods of the pattern; one of a hybrid's ten-layer period.
+            num_layers=min(self.num_layers,
+                           len(self.pattern) * (1 if self.mamba_heads else 2)),
             d_model=128,
             num_heads=heads,
             num_kv_heads=kv,
@@ -163,6 +201,9 @@ class ArchConfig:
             if self.num_experts
             else 0,
             d_rnn=128,
+            mamba_heads=4 if self.mamba_heads else 0,
+            mamba_head_dim=32,
+            mamba_d_state=16,
             encoder_layers=min(self.encoder_layers, 2),
             max_seq_len=512,
             mrope_sections=(8, 4, 4) if self.mrope_sections else None,

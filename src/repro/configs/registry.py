@@ -15,6 +15,7 @@ _ARCHS = (
     "qwen2_0_5b",
     "rwkv6_1_6b",
     "seamless_m4t_large_v2",
+    "granite_4_0_h_micro",
 )
 
 

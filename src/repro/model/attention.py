@@ -177,7 +177,7 @@ def apply_attention(
         base = jnp.arange(t, dtype=jnp.int32)[None]
         positions = jnp.broadcast_to(base, (b, t))
 
-    if not cross:
+    if not cross and cfg.rope:
         q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
 
@@ -233,6 +233,7 @@ def apply_attention(
             q, k, v,
             causal=causal and not cross,
             window=window,
+            scale=cfg.attention_multiplier,
             use_kernel=None,
         )
 
@@ -432,7 +433,8 @@ def _decode_attention(q, k_cache, v_cache, cur_pos, cfg, *, window=None):
     nkv = k_cache.shape[1]
     group = hq // nkv
     s = k_cache.shape[2]
-    scale = 1.0 / math.sqrt(hd)
+    scale = (1.0 / math.sqrt(hd) if cfg.attention_multiplier is None
+             else cfg.attention_multiplier)
 
     qg = q.reshape(b, nkv, group, t, hd)
     logits = jnp.einsum(

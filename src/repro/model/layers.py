@@ -86,6 +86,8 @@ def logits_projection(params, x: jax.Array, cfg) -> jax.Array:
             params["unembed"], ("embed", "vocab"), cfg.fsdp_gather_weights
         )
     logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.padded_vocab != cfg.vocab_size:
         # Mask pad rows so they can never win the softmax/argmax.
         vid = jnp.arange(cfg.padded_vocab)

@@ -115,7 +115,7 @@ def forward(
         x = jnp.concatenate(
             [frontend_embeds.astype(x.dtype), x[:, s_f:]], axis=1
         )
-    x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = x * jnp.asarray(cfg.embed_scale, x.dtype)
 
     x, _ = tf.apply_stack(
         params["decoder"], x, cfg, positions=positions, causal=True,
@@ -195,6 +195,13 @@ def _layer_state_shape(cfg, kind: str, batch: int, max_len: int,
         return RecState(
             h=jax.ShapeDtypeStruct((batch, h, RWKV_HEAD_DIM, RWKV_HEAD_DIM), jnp.float32),
             conv=jax.ShapeDtypeStruct((batch, 1, cfg.d_model), dt),
+        )
+    if kind == "mamba":
+        return RecState(
+            h=jax.ShapeDtypeStruct((batch, cfg.mamba_heads, cfg.mamba_head_dim,
+                                    cfg.mamba_d_state), jnp.float32),
+            conv=jax.ShapeDtypeStruct((batch, cfg.conv_width - 1,
+                                       cfg.mamba_conv_dim), dt),
         )
     raise ValueError(kind)
 
@@ -511,7 +518,7 @@ def decode_step(params, cfg, state, tokens: jax.Array, lengths: jax.Array,
     ).astype(jnp.int32)
     with jax.named_scope("model.embed"):
         x = embed_tokens(params["tok"], tokens, cfg)
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
     x, new_state = tf.apply_stack(
         params["decoder"], x, cfg, positions=positions, causal=True,
         states=state, enc_out=enc_out, token_mask=token_mask,

@@ -1,4 +1,5 @@
-"""Recurrent blocks: RG-LRU (RecurrentGemma/Griffin) and RWKV6 (Finch).
+"""Recurrent blocks: RG-LRU (RecurrentGemma/Griffin), RWKV6 (Finch) and
+Mamba-2 (SSD, as Granite-4.0-H and other hybrids use it).
 
 Both are direct consumers of the paper's technique: the hidden-state
 hand-off h[t-1] -> h[t] is literally ``fromThreadOrConst<h, Δ=1, C=h0>``
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.kernels.common import on_tpu
 from repro.kernels.elevator_scan.ops import elevator_scan
+from repro.kernels.ssd.ops import ssd
 from repro.kernels.token_shift.ops import token_shift
 from repro.kernels.wkv.ops import wkv_fused
 from repro.kernels.wkv.ref import wkv_chunked_ref, wkv_sequential_ref
@@ -41,7 +43,20 @@ class RecState(NamedTuple):
     """Decode-time state for one recurrent layer."""
 
     h: jax.Array           # RG-LRU hidden (B, d_rnn) | RWKV S (B, H, dk, dv)
+    #                        | Mamba-2 S (B, H, head_dim, d_state)
     conv: jax.Array        # conv tail (B, width-1, d_rnn) | x_prev (B, 1, D)
+    #                        | Mamba-2 conv tail (B, width-1, conv channels)
+
+
+class RecLayer(NamedTuple):
+    """One layer's view of a stacked recurrent state inside a decode
+    step's layer scan: the whole stacks ride the scan's carry and the
+    layer writes its own slice in place (the recurrent twin of
+    :class:`repro.model.attention.KVLayer`)."""
+
+    h: jax.Array           # (L, B, ...) stacked RecState.h
+    conv: jax.Array        # (L, B, width-1, channels) stacked RecState.conv
+    layer: jax.Array       # () int32
 
 
 # ==========================================================================
@@ -320,4 +335,112 @@ def apply_rwkv_block(params, x: jax.Array, cfg, *, state: RecState | None = None
             ext = jnp.concatenate([x_prev, x], axis=1)
             conv = jnp.take_along_axis(ext, counts[:, None, None], axis=1)
         new_state = RecState(h=S, conv=conv)
+    return constrain(out, "batch", "seq", "act_embed"), new_state
+
+
+# ==========================================================================
+# Mamba-2 (SSD)
+# ==========================================================================
+
+def init_mamba2_block(mk, cfg, name: str):
+    """Mamba-2 mixer.  The published input projection is three leaves:
+    ``w_z`` to the gate (d_inner), ``w_xbc`` to the conv channels x | B | C
+    (d_inner + 2 d_state) and ``w_dt`` to dt (one per head), each output
+    width a whole number of 128 lanes where the published one (8512) is
+    not, so no step relays the weight out.  ``conv_w[k]`` weighs the input
+    k tokens back.  The per-head ``A_log``, ``dt_bias`` and ``D`` and the
+    conv bias are (1, n) rows, not vectors."""
+    d, di, nh = cfg.d_model, cfg.mamba_d_inner, cfg.mamba_heads
+    cd = cfg.mamba_conv_dim
+    row = lambda n, tag: mk(f"{name}.{tag}", (1, n), (None, None),  # noqa: E731
+                            "normal", 1.0)
+    return {
+        "w_z": mk(f"{name}.w_z", (d, di), ("embed", None)),
+        "w_xbc": mk(f"{name}.w_xbc", (d, cd), ("embed", None)),
+        "w_dt": mk(f"{name}.w_dt", (d, nh), ("embed", None)),
+        "conv_w": mk(f"{name}.conv_w", (cfg.conv_width, cd), ("taps", None),
+                     "normal", cfg.conv_width ** -0.5),
+        "conv_b": row(cd, "conv_b"),
+        "dt_bias": row(nh, "dt_bias"),
+        "A_log": row(nh, "A_log"),
+        "D": row(nh, "D"),
+        "norm": init_rmsnorm(mk, di, f"{name}.norm"),
+        "w_out": mk(f"{name}.w_out", (di, d), (None, "embed")),
+    }
+
+
+def apply_mamba2_block(params, x: jax.Array, cfg, *,
+                       state: RecState | RecLayer | None = None,
+                       token_mask: jax.Array | None = None,
+                       use_kernel: bool | None = None):
+    """x: (B, T, D) -> ((B, T, D), new_state_or_None).
+
+        z, xBC, dt = x W_z, x W_xbc, x W_dt;  xBC = silu(conv4(xBC) + conv_b)
+        x, B, C = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        S_t = exp(A dt_t) S_{t-1} + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+        out = rmsnorm(y * silu(z)) W_out
+
+    ``token_mask`` (B, T) bool (stateful calls): a masked token has
+    ``dt = 0``, an identity step of the recurrence, and the conv tail is
+    gathered at each request's last valid tokens.  Must be a prefix mask.
+    ``state`` may be a :class:`RecLayer`: the layer's slices of the
+    stacked state are read and written in place, and a ``RecLayer``
+    comes back.
+    Stateful calls take the SSD kernels on TPU (the decode-window kernel
+    up to ``DECODE_WINDOW_MAX`` tokens, the chunk kernel beyond); the
+    stateless forward takes the jnp path unless ``use_kernel`` says.
+    """
+    b, t, _ = x.shape
+    nh, hp, n = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state
+    di, width = cfg.mamba_d_inner, cfg.conv_width
+    f32 = jnp.float32
+    z, xbc, dt_raw = (x @ params[w] for w in ("w_z", "w_xbc", "w_dt"))
+
+    stacked = isinstance(state, RecLayer)
+    if state is None:
+        hist = jnp.zeros((b, width - 1, xbc.shape[-1]), xbc.dtype)
+    elif stacked:
+        hist = jax.lax.dynamic_index_in_dim(state.conv, state.layer,
+                                            keepdims=False)
+    else:
+        hist = state.conv.astype(xbc.dtype)
+    ext = jnp.concatenate([hist, xbc], axis=1)
+    w = params["conv_w"].astype(f32)
+    conv = sum(w[k] * ext[:, width - 1 - k:width - 1 - k + t].astype(f32)
+               for k in range(width)) + params["conv_b"].astype(f32)
+    xbc = jax.nn.silu(conv).astype(x.dtype)
+    xs, bm, cm = jnp.split(xbc, [di, di + n], axis=-1)
+
+    dt = jax.nn.softplus(dt_raw.astype(f32) + params["dt_bias"].astype(f32))
+    if token_mask is not None and state is not None:
+        dt = jnp.where(token_mask[:, :, None], dt, 0.0)
+    a = -jnp.exp(params["A_log"].astype(f32)[0])                # (H,)
+    h0 = (state.h if state is not None
+          else jnp.zeros((b, nh, hp, n), f32))
+    if use_kernel is None:
+        use_kernel = on_tpu() and state is not None
+    xh = xs.reshape(b, t, nh, hp)
+    with jax.named_scope("model.mamba.ssd"):
+        y, s_new = ssd(xh, dt, a, bm, cm, h0,
+                       state.layer if stacked else None,
+                       decode=state is not None, use_kernel=use_kernel)
+    y = y.astype(f32) + params["D"].astype(f32)[0, :, None] * xh.astype(f32)
+    g = y.reshape(b, t, di) * jax.nn.silu(z.astype(f32))
+    out = rms_norm(params["norm"], g, cfg.norm_eps).astype(x.dtype)
+    out = out @ params["w_out"]
+
+    new_state = None
+    if state is not None:
+        if token_mask is None:
+            tail = ext[:, t:]
+        else:
+            counts = jnp.sum(token_mask, axis=1, dtype=jnp.int32)
+            idx = counts[:, None] + jnp.arange(width - 1, dtype=jnp.int32)[None]
+            tail = jnp.take_along_axis(ext, idx[:, :, None], axis=1)
+        if stacked:
+            new_state = RecLayer(s_new, jax.lax.dynamic_update_index_in_dim(
+                state.conv, tail.astype(state.conv.dtype), state.layer, 0),
+                state.layer)
+        else:
+            new_state = RecState(h=s_new, conv=tail)
     return constrain(out, "batch", "seq", "act_embed"), new_state

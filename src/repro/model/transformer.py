@@ -29,7 +29,7 @@ ATTN_KINDS = ("attn", "local", "global")
 #: Trace scope of each block kind's sequence mixer (``jax.named_scope``).
 MIXER_SCOPES = {"attn": "model.mixer.attn", "local": "model.mixer.attn",
                 "global": "model.mixer.attn", "rec": "model.mixer.rglru",
-                "rwkv": "model.mixer.rwkv"}
+                "rwkv": "model.mixer.rwkv", "mamba": "model.mixer.mamba"}
 
 
 # --------------------------------------------------------------------------
@@ -45,6 +45,8 @@ def init_block(mk, cfg, kind: str, name: str, *, cross: bool = False):
         p["rec"] = rec_mod.init_rglru_block(mk, cfg, f"{name}.rec")
     elif kind == "rwkv":
         p["rwkv"] = rec_mod.init_rwkv_block(mk, cfg, f"{name}.rwkv")
+    elif kind == "mamba":
+        p["mamba"] = rec_mod.init_mamba2_block(mk, cfg, f"{name}.mamba")
     else:
         raise ValueError(kind)
     if cross:
@@ -78,10 +80,13 @@ def apply_block(
         elif kind == "rec":
             out, new_state = rec_mod.apply_rglru_block(
                 params["rec"], h, cfg, state=state, token_mask=token_mask)
+        elif kind == "mamba":
+            out, new_state = rec_mod.apply_mamba2_block(
+                params["mamba"], h, cfg, state=state, token_mask=token_mask)
         else:
             out, new_state = rec_mod.apply_rwkv_block(
                 params["rwkv"], h, cfg, state=state, token_mask=token_mask)
-    x = x + out
+    x = x + _residual(out, cfg)
 
     if enc_out is not None and "cross" in params:
         h = rms_norm(params["ln_cross"], x, cfg.norm_eps)
@@ -101,8 +106,17 @@ def apply_block(
                 out = moe_mod.apply_moe(params["ffn"], h, cfg)
         else:
             out = apply_mlp(params["ffn"], h, cfg)
-    x = x + out
+    x = x + _residual(out, cfg)
     return constrain(x, "batch", "seq", "act_embed"), new_state
+
+
+def _residual(out, cfg):
+    """A block branch as it joins the residual stream: scaled by the
+    configuration's residual multiplier (granite's 0.22), untouched at
+    the default 1."""
+    if cfg.residual_multiplier == 1.0:
+        return out
+    return out * jnp.asarray(cfg.residual_multiplier, out.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -199,7 +213,7 @@ def apply_stack(
             x, _ = jax.lax.scan(
                 scan_body, x, stack_params["scanned"], unroll=scan_unroll()
             )
-        elif not any(map(attn_mod.writes_in_place, states["scanned"])):
+        elif not any(map(_carried, states["scanned"], pattern)):
             def scan_body(carry, inputs):
                 period_params, period_states = inputs
                 y, ns = period_fn(carry, period_params, period_states)
@@ -209,9 +223,9 @@ def apply_stack(
                 unroll=scan_unroll(),
             )
         else:
-            x, new_scan_states = _scan_carrying_kv(
+            x, new_scan_states = _scan_carrying(
                 period_fn, x, stack_params["scanned"], states["scanned"],
-                n_periods)
+                pattern, n_periods)
 
     new_rem_states = []
     for i, (sub_params, kind) in enumerate(zip(stack_params["remainder"], remainder)):
@@ -227,40 +241,64 @@ def apply_stack(
     return x, {"scanned": new_scan_states, "remainder": new_rem_states}
 
 
-def _scan_carrying_kv(period_fn, x, scanned_params, scanned_states,
-                      n_periods: int):
-    """The layer scan of a decode step whose dense KV caches take the
-    in-place insert (:func:`repro.model.attention.writes_in_place`).
+def _carried(state, kind: str) -> str | None:
+    """How a layer's stacked decode state rides the layer scan's carry:
+    ``"kv"`` for a dense KV cache that takes the in-place insert
+    (:func:`repro.model.attention.writes_in_place`), ``"rec"`` for a
+    Mamba-2 state (written in place by its layer), else None (scanned as
+    ``xs``/``ys``)."""
+    if attn_mod.writes_in_place(state):
+        return "kv"
+    if kind == "mamba":
+        return "rec"
+    return None
 
-    Scanned as ``xs`` and rebuilt as ``ys``, each layer's cache would be
-    copied whole at every step, however little the insert wrote.  So the
-    stacked K and V buffers ride the carry instead: each attention layer
-    gets them with its index (a :class:`repro.model.attention.KVLayer`)
-    and writes its rows of them in place.  Their lengths, and every other
-    state, stay ``xs``/``ys``.
+
+def _scan_carrying(period_fn, x, scanned_params, scanned_states, pattern,
+                   n_periods: int):
+    """The layer scan of a decode step in which some layers' states are
+    written in place (:func:`_carried`).
+
+    Scanned as ``xs`` and rebuilt as ``ys``, each layer's state would be
+    copied whole at every step, however little the step wrote, and a
+    second copy of the whole state held while it runs.  So those stacked
+    buffers ride the carry instead: a dense KV cache's K and V (each
+    attention layer gets them with its index, a
+    :class:`repro.model.attention.KVLayer`, and writes its rows), a
+    Mamba-2 layer's state and conv tail (a
+    :class:`repro.model.recurrent.RecLayer`).  KV lengths, and every
+    other state, stay ``xs``/``ys``.
     """
-    carried = [attn_mod.writes_in_place(s) for s in scanned_states]
-    xs_states = [s.length if c else s
+    carried = [_carried(s, k) for s, k in zip(scanned_states, pattern)]
+    xs_states = [s.length if c == "kv" else None if c else s
                  for s, c in zip(scanned_states, carried)]
-    kv0 = [(s.k, s.v) if c else None for s, c in zip(scanned_states, carried)]
+    bufs0 = [(s.k, s.v) if c == "kv" else (s.h, s.conv) if c else None
+             for s, c in zip(scanned_states, carried)]
+
+    def view(buf, st, c, layer):
+        if c == "kv":
+            return attn_mod.KVLayer(*buf, st, layer)
+        return rec_mod.RecLayer(*buf, layer) if c else st
 
     def scan_body(carry, inputs):
-        y, kv = carry
+        y, bufs = carry
         period_params, period_states, layer = inputs
-        views = [attn_mod.KVLayer(*kv[j], st, layer) if c else st
+        views = [view(bufs[j], st, c, layer)
                  for j, (st, c) in enumerate(zip(period_states, carried))]
         y, ns = period_fn(y, period_params, views)
-        kv = [(n.k, n.v) if c else None for n, c in zip(ns, carried)]
-        ys = [n.length if c else n for n, c in zip(ns, carried)]
-        return (y, kv), ys
+        bufs = [(n.k, n.v) if c == "kv" else (n.h, n.conv) if c else None
+                for n, c in zip(ns, carried)]
+        ys = [n.length if c == "kv" else None if c else n
+              for n, c in zip(ns, carried)]
+        return (y, bufs), ys
 
-    (x, kv), ys = jax.lax.scan(
-        scan_body, (x, kv0),
+    (x, bufs), ys = jax.lax.scan(
+        scan_body, (x, bufs0),
         (scanned_params, xs_states, jnp.arange(n_periods, dtype=jnp.int32)),
         unroll=scan_unroll(),
     )
-    return x, [KVCache(*kv[j], n) if c else n
-               for j, (n, c) in enumerate(zip(ys, carried))]
+    return x, [KVCache(*bufs[j], n) if c == "kv" else RecState(*bufs[j]) if c
+               else n for j, (n, c) in enumerate(zip(ys, carried))]
 
 
 def _remat_policy(cfg):

@@ -243,6 +243,7 @@ def audit_jit_entrypoints(cfg, *, batch: int = 2, max_len: int = 64,
     i32, b = jnp.int32, batch
     vec = sds((b,), i32)
     key = sds((2,), jnp.uint32)
+    rows = cfg.admit_rows if 0 < cfg.admit_rows < b else None
     here = "src/repro/serve/engine.py:ServeEngine"
     return [
         JitEntry(
@@ -268,10 +269,10 @@ def audit_jit_entrypoints(cfg, *, batch: int = 2, max_len: int = 64,
             f"{here}._serve_window", donor="_serve_window",
         ),
         JitEntry(
-            "serve.admit", eng._admit_step(p, 0.0, 0, None),
-            (params, state, sds((b, p), i32), sds((b,), jnp.bool_), vec,
-             vec, vec, vec, vec, vec, sds((b,), jnp.bool_),
-             sds((b, 1), i32), key),
+            "serve.admit", eng._admit_step(p, 0.0, 0, None, rows),
+            (params, state, sds((rows or b, p), i32), sds((b,), jnp.bool_),
+             vec, vec, vec, vec, vec, vec, sds((b,), jnp.bool_),
+             sds((b, 1), i32), key) + ((sds((rows,), i32),) if rows else ()),
             f"{here}._admit_step", donor="_admit_step",
         ),
         JitEntry(
@@ -488,6 +489,55 @@ def _reset_slot_rows(state, rows: jax.Array):
     )
 
 
+def _batch_axis(node) -> int:
+    """Slot axis of a dense decode-state node (stacked ``(L, B, ...)`` or
+    not)."""
+    if isinstance(node, KVCache):
+        return node.k.ndim - 4
+    if isinstance(node, RecState):
+        return node.conv.ndim - 3
+    raise TypeError(type(node))
+
+
+def _is_dense_node(x) -> bool:
+    return isinstance(x, (KVCache, RecState))
+
+
+def _state_rows(state, rows: jax.Array):
+    """The slots ``rows`` (R,) int32 of a dense decode state, in that
+    order: a state of R slots."""
+
+    def take(node):
+        ax = _batch_axis(node)
+        if isinstance(node, KVCache):
+            length = jnp.broadcast_to(node.length, node.k.shape[:ax + 1])
+            return KVCache(k=jnp.take(node.k, rows, axis=ax),
+                           v=jnp.take(node.v, rows, axis=ax),
+                           length=jnp.take(length, rows, axis=ax))
+        return RecState(h=jnp.take(node.h, rows, axis=ax),
+                        conv=jnp.take(node.conv, rows, axis=ax))
+
+    return jax.tree.map(take, state, is_leaf=_is_dense_node)
+
+
+def _put_state_rows(state, part, rows: jax.Array):
+    """``state`` with its slots ``rows`` (R,) int32, distinct, replaced by
+    ``part`` (a state of R slots, as :func:`_state_rows` gives); every
+    other slot's bits are untouched."""
+
+    def put(node, new):
+        at = (slice(None),) * _batch_axis(node) + (rows,)
+        if isinstance(node, KVCache):
+            length = jnp.broadcast_to(node.length, node.k.shape[:len(at)])
+            return KVCache(k=node.k.at[at].set(new.k),
+                           v=node.v.at[at].set(new.v),
+                           length=length.at[at].set(new.length))
+        return RecState(h=node.h.at[at].set(new.h),
+                        conv=node.conv.at[at].set(new.conv))
+
+    return jax.tree.map(put, state, part, is_leaf=_is_dense_node)
+
+
 @jax.named_scope("serve.sample")
 def _sample_tokens(logits, base_key, req_ids, tok_idx, temperature, top_k):
     """Sample one token per slot from ``logits`` (B, V).
@@ -637,8 +687,9 @@ class ServeEngine:
     # ------------------------------------------------------------------
 
     def _admit_step(self, p: int, temperature: float, top_k: int,
-                    eos_id: int | None):
-        """Jitted slot admission, cached per (prompt bucket, sampling cfg).
+                    eos_id: int | None, rows: int | None = None):
+        """Jitted slot admission, cached per (prompt bucket, sampling cfg,
+        rows).
 
         Re-prefills the admitted slots' prompts into the shared decode
         state without touching neighbors: admitted rows are zeroed
@@ -653,24 +704,26 @@ class ServeEngine:
         sampling keys then guarantee the resumed stream is the one the
         fault interrupted.
 
+        With ``rows`` (the model's ``admit_rows``, below the slot count)
+        the prefill runs on ``rows`` slots only: a last operand ``sel``
+        (rows,) int32 of distinct slots, the admitted ones among them,
+        picks them out of the state, ``tokens`` is (rows, P) in ``sel``'s
+        order, and the prefilled rows are written back in place.  The
+        prefill then costs rows / B of the all-slots one; the slots
+        outside ``sel`` are not read.
+
         With an engine ``mesh`` the admission prefill runs under the same
         sharding rules :func:`make_cache_prefill_step` would pick for a
         prompt of this bucket (``prefill_seq`` at/above
         :data:`SEQ_PREFILL_MIN_T`, plain ``prefill`` below).
         """
-        key = (p, temperature, top_k, eos_id)
+        key = (p, temperature, top_k, eos_id, rows)
         fn = self._admits.get(key)
         if fn is None:
             cfg, max_len = self.cfg, self.max_len
 
-            def admit(params, state, tokens, admit_row, plen, tok_idx,
-                      lengths, counts, budgets, req_ids, active, cur,
-                      base_key):
-                # Entry checksum: the state as handed to this dispatch,
-                # *before* any mutation — the host chains it against the
-                # previous dispatch's exit checksum to catch silent
-                # corruption of non-admitted rows between dispatches.
-                csum_in = _state_checksum(state)
+            def prefill(params, state, tokens, admit_row, plen, tok_idx,
+                        req_ids, base_key):
                 state = _reset_slot_rows(state, admit_row)
                 mask = admit_row[:, None] & (
                     jnp.arange(p, dtype=jnp.int32)[None, :] < plen[:, None]
@@ -683,6 +736,28 @@ class ServeEngine:
                     logits[:, -1], base_key, req_ids, tok_idx,
                     temperature, top_k,
                 )
+                return state, tok0
+
+            def admit(params, state, tokens, admit_row, plen, tok_idx,
+                      lengths, counts, budgets, req_ids, active, cur,
+                      base_key, *sel):
+                # Entry checksum: the state as handed to this dispatch,
+                # *before* any mutation — the host chains it against the
+                # previous dispatch's exit checksum to catch silent
+                # corruption of non-admitted rows between dispatches.
+                csum_in = _state_checksum(state)
+                if rows is None:
+                    state, tok0 = prefill(params, state, tokens, admit_row,
+                                          plen, tok_idx, req_ids, base_key)
+                else:
+                    (sel,) = sel
+                    part, tok_sel = prefill(
+                        params, _state_rows(state, sel), tokens,
+                        admit_row[sel], plen[sel], tok_idx[sel],
+                        req_ids[sel], base_key)
+                    state = _put_state_rows(state, part, sel)
+                    tok0 = jnp.zeros(admit_row.shape,
+                                     tok_sel.dtype).at[sel].set(tok_sel)
                 lengths = jnp.where(admit_row, plen, lengths)
                 counts = jnp.where(admit_row, tok_idx + 1, counts)
                 done = counts >= budgets
@@ -1426,6 +1501,10 @@ class ServeSession:
         ) if live else 1
         insert_window = max(k_w, _bucket32(worst))
         self.insert_window = insert_window
+        #: Slots one dense admission prefills (the model's
+        #: ``admit_rows``); ``None``: all ``b``.
+        r = int(eng.cfg.admit_rows)
+        self.admit_rows = r if 0 < r < b and not eng.paged else None
         ctl = None
         if eng.paged:
             # One shared-page region per registered prefix in use this
@@ -1647,7 +1726,10 @@ class ServeSession:
         take: list[int] = []
         slot_alloc: dict[int, tuple] = {}
         group_pid: int | None = None
-        while len(take) < len(free) and (self.recover_q or self.pending):
+        cap = len(free)
+        if self.admit_rows is not None:
+            cap = min(cap, self.admit_rows)
+        while len(take) < cap and (self.recover_q or self.pending):
             q = self.recover_q if self.recover_q else self.pending
             ri = q[0]
             if ctl is not None:
@@ -1705,13 +1787,25 @@ class ServeSession:
                         self.slot_req[slot] = ri
                     self.budgets = jnp.asarray(bud_np)
                     self.req_ids = jnp.asarray(rid_np)
+                    r = self.admit_rows
+                    sel = ()
+                    if r is not None:
+                        # The admitted slots, then idle ones, then busy
+                        # ones (masked: their bits pass through).
+                        rest = sorted(
+                            set(range(b)) - set(used),
+                            key=lambda slot: bool(self.active_np[slot]))
+                        picked = np.array(used + rest[: r - len(used)],
+                                          np.int32)
+                        tok_np = tok_np[picked]
+                        sel = (jnp.asarray(picked),)
                     fn = eng._admit_step(
-                        p_b, self.temperature, self.top_k, self.eos_id)
+                        p_b, self.temperature, self.top_k, self.eos_id, r)
                     args = (eng.params, self.state, jnp.asarray(tok_np),
                             jnp.asarray(admit_np), jnp.asarray(plen_np),
                             jnp.asarray(tokidx_np), self.lengths,
                             self.counts, self.budgets, self.req_ids,
-                            self.active, self.cur, self.base_key)
+                            self.active, self.cur, self.base_key) + sel
                 else:
                     # Paged: only the suffix past each request's shared-
                     # prefix start is prefilled; the prefix rides in as

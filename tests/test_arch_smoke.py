@@ -130,6 +130,7 @@ class TestConfigs:
             "qwen2-0.5b": (24, 896, 14, 2, 4864, 151936),
             "rwkv6-1.6b": (24, 2048, 32, 32, 7168, 65536),
             "seamless-m4t-large-v2": (24, 1024, 16, 16, 8192, 256206),
+            "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
         }
         for arch_name in list_archs():
             cfg = get_config(arch_name)
@@ -155,6 +156,7 @@ class TestConfigs:
             "rwkv6-1.6b": (1.0e9, 2.4e9),
             "gemma3-1b": (0.6e9, 1.6e9),
             "recurrentgemma-2b": (1.6e9, 3.8e9),
+            "granite-4.0-h-micro": (2.5e9, 4.0e9),
         }
         for name, (lo, hi) in bands.items():
             n = get_config(name).param_count()

@@ -10,9 +10,14 @@ Shapes are the published widths the serve and train paths use:
 ``rwkv6-1.6b`` WKV (H 32, Dh 64, bf16), ``recurrentgemma-2b`` RG-LRU and
 temporal conv (d_rnn 2560), ``gemma3-1b`` attention (4 x 256 heads, one
 KV head, window 1024), ``qwen2-0.5b`` KV row insert (24 layers, 32
-slots, 2 KV heads of 64, 2048 positions).  The ``qwen2-0.5b`` serve
-window and admit compile whole for one chip, the row kernel in the
-window, both with their decode state aliased in place.
+slots, 2 KV heads of 64, 2048 positions), ``granite-4.0-h-micro`` SSD
+(32 slots, 64 heads of 64, d_state 128: the chunk kernel at the cell's
+largest and a padded admit bucket, the decode-window kernel at a decode
+step and the widest window).  The ``qwen2-0.5b`` serve window and admit
+compile whole for one chip, the row kernel in the window, both with
+their decode state aliased in place; so does the ``granite-4.0-h-micro``
+window at all 40 layers, its SSM state updated in place, and its admit
+at the cell's largest bucket on the four slots ``admit_rows`` picks.
 
 The mesh cases compile the serve engine's jits for all four chips of the
 topology: ``rwkv6-1.6b`` at its widths with the depth cut to two layers,
@@ -36,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import repro.kernels.kv_insert.ops as kv_ops
+import repro.kernels.ssd.ops as ssd_ops
 import repro.kernels.wkv.ops as wkv_ops
 import repro.model.recurrent as rec
 from repro.configs.registry import get_config
@@ -44,6 +50,8 @@ from repro.kernels.elevator_scan.decode import elevator_decode_window_pallas
 from repro.kernels.elevator_scan.kernel import elevator_scan_pallas
 from repro.kernels.kv_insert.kernel import kv_row_insert
 from repro.kernels.local_attention.kernel import flash_attention_pallas
+from repro.kernels.ssd.decode import ssd_decode_window_pallas
+from repro.kernels.ssd.kernel import ssd_chunk_pallas
 from repro.kernels.token_shift.kernel import token_shift_pallas
 from repro.kernels.wkv.bwd import wkv_pallas_bwd
 from repro.kernels.wkv.decode import wkv_decode_pallas, wkv_decode_window_pallas
@@ -146,6 +154,15 @@ def _kv_row(s):
             (cache, cache, row, row, s((32,), jnp.int32), s((), jnp.int32)))
 
 
+def _ssd(s, t, window):
+    slots, heads, dh, ds = 32, 64, 64, 128          # granite-4.0-h-micro
+    args = (s((slots, t, heads, dh), BF16), s((slots, t, heads), F32),
+            s((heads,), F32), s((slots, t, ds), BF16), s((slots, t, ds), BF16))
+    fn = ssd_decode_window_pallas if window else ssd_chunk_pallas
+    return (jax.jit(fn, donate_argnums=(5,)),
+            args + (s((36, slots, heads, dh, ds), F32), s((), jnp.int32)))
+
+
 #: name -> builder(sds) -> (jitted fn, abstract args).  ``sds(shape,
 #: dtype)`` places a ShapeDtypeStruct on the described chip.
 CASES = {
@@ -171,6 +188,12 @@ CASES = {
     "token_shift.t4": lambda s: _token_shift(s, 4),
     "local_attention.flash": _flash,
     "kv_insert.row": _kv_row,
+    # SSD: admit buckets 512 and 96 (padded to whole chunks); a decode
+    # step and a 64-token admission window over the 36-layer state stack.
+    "ssd.chunk.t512": lambda s: _ssd(s, 512, False),
+    "ssd.chunk.t96": lambda s: _ssd(s, 96, False),
+    "ssd.decode_window.k1": lambda s: _ssd(s, 1, True),
+    "ssd.decode_window.k64": lambda s: _ssd(s, 64, True),
 }
 
 
@@ -255,3 +278,62 @@ def test_dense_serve_programs_compile_with_the_row_insert(
     if jit == "serve.serve_window":
         text = entry.fn.lower(*args).compile().as_text()
         assert "kv_row_insert" in text, "no row kernel in the window"
+
+
+def test_granite_window_compiles_with_the_ssd_state_in_place(
+        one_chip, monkeypatch, no_compile_cache):
+    """The ``granite-4.0-h-micro`` decode window at its cell's sizes (40
+    layers, 32 slots, 1024 positions) for one chip: the SSD decode
+    kernel is in it, the state aliased, and no second copy of the 2.7 GB
+    state is held while it runs."""
+    from repro.analysis.donation import check_entry
+
+    for mod in (kv_ops, ssd_ops):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    monkeypatch.setattr(rec, "on_tpu", lambda: True)
+    cfg = get_config("granite-4.0-h-micro")
+    entry = {e.name: e for e in serve_engine.audit_jit_entrypoints(
+        cfg, batch=32, max_len=1024, decode_window=8, prompt=64)}[
+        "serve.serve_window"]
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        entry.args)
+    entry = dataclasses.replace(entry, args=args)
+    assert [f.severity.name for f in check_entry(entry)] == ["INFO"]
+    compiled = entry.fn.lower(*args).compile()
+    assert "ssd_decode_window_pallas" in compiled.as_text()
+    state = sum(np.prod(a.shape) * a.dtype.itemsize
+                for a in jax.tree.leaves(args[1]))
+    assert compiled.memory_analysis().temp_size_in_bytes < state / 2
+
+
+def test_granite_admit_compiles_on_its_rows_in_place(
+        one_chip, monkeypatch, no_compile_cache):
+    """The ``granite-4.0-h-micro`` admit at its cell's sizes and largest
+    bucket (512 tokens) on the four slots ``admit_rows`` picks of 32: the
+    SSD chunk kernel is in it, the 32-slot state aliased, and its
+    temporaries (the four slots' state, their activations) under half
+    of that state: the other slots are not copied."""
+    from repro.analysis.donation import check_entry
+
+    for mod in (kv_ops, ssd_ops):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    monkeypatch.setattr(rec, "on_tpu", lambda: True)
+    cfg = get_config("granite-4.0-h-micro")
+    assert cfg.admit_rows == 4
+    entry = {e.name: e for e in serve_engine.audit_jit_entrypoints(
+        cfg, batch=32, max_len=1024, decode_window=8, prompt=512)}[
+        "serve.admit"]
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        entry.args)
+    assert args[2].shape == (4, 512) and args[-1].shape == (4,)
+    entry = dataclasses.replace(entry, args=args)
+    assert [f.severity.name for f in check_entry(entry)] == ["INFO"]
+    compiled = entry.fn.lower(*args).compile()
+    assert "ssd_chunk_pallas" in compiled.as_text()
+    state = sum(np.prod(a.shape) * a.dtype.itemsize
+                for a in jax.tree.leaves(args[1]))
+    assert compiled.memory_analysis().temp_size_in_bytes < state / 2

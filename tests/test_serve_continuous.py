@@ -15,7 +15,7 @@ from repro.serve.engine import Request, ServeEngine, make_cache_prefill_step
 
 jax.config.update("jax_platform_name", "cpu")
 
-ARCHS = ["rwkv6-1.6b", "gemma3-1b", "recurrentgemma-2b"]
+ARCHS = ["rwkv6-1.6b", "gemma3-1b", "recurrentgemma-2b", "granite-4.0-h-micro"]
 
 
 def _setup(arch, seed=0):

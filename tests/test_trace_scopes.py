@@ -22,7 +22,9 @@ SERVE = ("serve.state_check", "serve.sample", "model.embed", "model.mlp",
          "model.head")
 #: Scopes of each model's sequence mixer.
 MIXER = {"rwkv6-1.6b": ("model.mixer.rwkv",),
-         "qwen2-0.5b": ("model.mixer.attn", "model.attn.decode")}
+         "qwen2-0.5b": ("model.mixer.attn", "model.attn.decode"),
+         "granite-4.0-h-micro": ("model.mixer.mamba", "model.mamba.ssd",
+                                 "model.mixer.attn", "model.attn.decode")}
 #: Programs (by their donation-audit registration name) and the scopes
 #: only they hold.
 PROGRAMS = {"serve.admit": ("serve.reset_rows",),
@@ -59,7 +61,8 @@ def test_programs_carry_their_scopes(lowered, program):
 
 def test_mixer_scope_follows_the_block_kind(lowered):
     arch, texts = lowered
-    other = {s for a, ss in MIXER.items() if a != arch for s in ss}
+    other = {s for a, ss in MIXER.items() if a != arch
+             for s in ss} - set(MIXER[arch])
     for text in texts.values():
         assert not _scopes_in(text, other)
 
@@ -81,7 +84,7 @@ def test_insert_scope_marks_the_in_place_write(max_len, dense_insert):
             if e.name not in PROGRAMS:
                 continue
             text = e.fn.lower(*e.args).as_text(debug_info=True)
-            want = (dense_insert and arch == "qwen2-0.5b"
+            want = (dense_insert and "model.mixer.attn" in MIXER[arch]
                     and not e.name.startswith("serve.paged"))
             assert bool(_scopes_in(text, ["model.attn.insert"])) == want, (
                 arch, e.name)
