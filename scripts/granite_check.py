@@ -11,7 +11,10 @@ against their references, at the benchmark cell's sizes.
    prefill from a nonzero state (three chunks, the last one partial) and
    windows of 1, 8 and 64 tokens, with decays from exp(-0.05) to below
    exp(-20) a token.  Error: the largest absolute difference over the
-   largest absolute value of the reference, for y and the state.
+   largest absolute value of the reference, for y and the state.  Then
+   the decode window over 32 rows with three live and with none: the
+   live rows as above, every dead row (one of them NaN) bit for bit and
+   its y zero (the changed values counted, limit 0).
 2. Prefill then decode through the decode state, as the engine's admit
    and window programs do (``model.decode_step`` under jit: a masked
    320-token admission of two prompts, 300 and 40 tokens, then 16 single
@@ -75,6 +78,37 @@ def kernel_errors(tiny: bool) -> dict:
         y0, s0 = ssd_sequential_ref(*args)
         out[f"{name}.t{t}.y"] = rel_error(y, y0)
         out[f"{name}.t{t}.state"] = rel_error(s[0], s0)
+    out.update(live_row_errors(tiny, interp))
+    return out
+
+
+def live_row_errors(tiny: bool, interp: bool) -> dict:
+    """The decode window over 32 rows of which three are live, one dead
+    row's state NaN, and over none live: the live rows against the
+    recurrence; dead rows' state bits changed and y values nonzero,
+    counted (their limit is 0)."""
+    from repro.kernels.ssd.decode import ssd_decode_window_pallas
+    from repro.kernels.ssd.ref import (random_inputs, rel_error,
+                                       ssd_sequential_ref)
+
+    shape = (32, 8, 16, 16) if tiny else (32, 64, 64, 128)
+    out = {}
+    for name, rows in (("three", (0, 5, 31)), ("none", ())):
+        x, dt, a, bm, cm, h0 = random_inputs(shape, 1, seed=7)
+        live = np.isin(np.arange(32), rows)
+        h0 = h0.at[1, 0, 0, 0].set(jnp.nan)
+        y, s = ssd_decode_window_pallas(x, dt, a, bm, cm, h0[None], 0,
+                                        jnp.asarray(live), interpret=interp)
+        if live.any():
+            y0, s0 = ssd_sequential_ref(x[live], dt[live], a, bm[live],
+                                        cm[live], h0[live])
+            out[f"live_rows.{name}.y"] = rel_error(y[live], y0)
+            out[f"live_rows.{name}.state"] = rel_error(s[0][live], s0)
+        bits = lambda v: np.asarray(v, np.float32).view(np.uint32)  # noqa: E731
+        out[f"live_rows.{name}.dead_state_changed"] = float(np.sum(
+            bits(s[0][~live]) != bits(h0[~live])))
+        out[f"live_rows.{name}.dead_y_nonzero"] = float(np.sum(
+            np.asarray(y[~live], np.float32) != 0))
     return out
 
 
@@ -124,6 +158,7 @@ def main(argv=None) -> int:
     print(json.dumps(errs), file=sys.stderr, flush=True)
     served = served_errors(a.tiny)
     checks = {k: {"value": v, "limit": STATE_TOL if k.endswith("state")
+                  else 0.0 if k.startswith("live_rows.") and "dead" in k
                   else OUT_TOL} for k, v in errs.items()}
     checks["served_logits_sd"] = {"value": served["served"],
                                   "limit": LOGIT_SHARE * served["fp8"]}

@@ -383,6 +383,8 @@ def apply_mamba2_block(params, x: jax.Array, cfg, *,
     ``token_mask`` (B, T) bool (stateful calls): a masked token has
     ``dt = 0``, an identity step of the recurrence, and the conv tail is
     gathered at each request's last valid tokens.  Must be a prefix mask.
+    A row with no valid token is skipped by the decode-window kernel: its
+    state is not touched and its SSD output is zero.
     ``state`` may be a :class:`RecLayer`: the layer's slices of the
     stacked state are read and written in place, and a ``RecLayer``
     comes back.
@@ -412,8 +414,10 @@ def apply_mamba2_block(params, x: jax.Array, cfg, *,
     xs, bm, cm = jnp.split(xbc, [di, di + n], axis=-1)
 
     dt = jax.nn.softplus(dt_raw.astype(f32) + params["dt_bias"].astype(f32))
+    live = None
     if token_mask is not None and state is not None:
         dt = jnp.where(token_mask[:, :, None], dt, 0.0)
+        live = jnp.any(token_mask, axis=1)
     a = -jnp.exp(params["A_log"].astype(f32)[0])                # (H,)
     h0 = (state.h if state is not None
           else jnp.zeros((b, nh, hp, n), f32))
@@ -422,7 +426,7 @@ def apply_mamba2_block(params, x: jax.Array, cfg, *,
     xh = xs.reshape(b, t, nh, hp)
     with jax.named_scope("model.mamba.ssd"):
         y, s_new = ssd(xh, dt, a, bm, cm, h0,
-                       state.layer if stacked else None,
+                       state.layer if stacked else None, live,
                        decode=state is not None, use_kernel=use_kernel)
     y = y.astype(f32) + params["D"].astype(f32)[0, :, None] * xh.astype(f32)
     g = y.reshape(b, t, di) * jax.nn.silu(z.astype(f32))

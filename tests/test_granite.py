@@ -289,3 +289,53 @@ def test_session_admits_at_most_admit_rows_a_step(model):
         sess.close()
     assert admissions == [(1, 4), (1, 2)]
     assert outs[0] == outs[1]
+
+
+def _ssm_rows(state, slot):
+    """Slot ``slot``'s SSM state (each Mamba layer's h and conv tail)."""
+    rec = [n for n in jax.tree.leaves(
+        state, is_leaf=lambda n: isinstance(n, RecState))
+        if isinstance(n, RecState)]
+    return [np.take(np.asarray(a), slot, axis=a.ndim - d).view(np.uint32)
+            for n in rec for a, d in ((n.h, 4), (n.conv, 3))]
+
+
+def test_served_window_leaves_dead_slots_alone(model, monkeypatch):
+    """A served window with the SSD decode kernel forced (interpreted):
+    slot 1 idle, slot 2 finishing after two of the four tokens.  Slot 1's
+    SSM state stays bit for bit through the window, slot 2's from the
+    token it goes dead (against a two-token window), and the live slots'
+    tokens are those of the oracle path."""
+    cfg, params, seqs, _ = model
+    p_max = 64
+    prompts = [s[:n] for s, n in zip(seqs, LENS)] + [np.arange(5, 25)]
+    tok = np.zeros((SLOTS, p_max), np.int32)
+    mask = np.zeros((SLOTS, p_max), bool)
+    for i, s in enumerate(prompts):
+        tok[i, :s.size], mask[i, :s.size] = s, True
+    state = M.init_decode_state(cfg, SLOTS, MAX_LEN, insert_window=p_max)
+    _, state = _step(cfg)(params, state, jnp.asarray(tok), jnp.int32(0),
+                          jnp.asarray(mask))
+    i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    args = (i32([[seqs[0][LENS[0]]], [seqs[1][LENS[1]]], [7]]),
+            i32([s.size for s in prompts]), i32([0, 0, 0]), i32([9, 9, 2]),
+            jnp.asarray([True, False, True]), i32([0, 1, 2]),
+            jax.random.key_data(jax.random.key(0)))
+
+    def window(k, kernel):
+        monkeypatch.setattr(R, "on_tpu", lambda: kernel)
+        eng = E.ServeEngine(cfg, params, max_len=MAX_LEN, decode_window=k)
+        out = eng._serve_window(k, 0.0, 0, None)(
+            params, jax.tree.map(jnp.copy, state), *args)
+        return out[0], np.asarray(out[6]), np.asarray(out[7])
+
+    st4, toks, emits = window(4, True)
+    st2, _, _ = window(2, True)
+    _, want, want_emits = window(4, False)
+    assert emits[:, 1].sum() == 0 and list(emits[:, 2]) == [1, 1, 0, 0]
+    for a, b in zip(_ssm_rows(st4, 1), _ssm_rows(state, 1)):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(_ssm_rows(st4, 2), _ssm_rows(st2, 2)):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(emits, want_emits)
+    np.testing.assert_array_equal(toks[emits], want[emits])

@@ -2,10 +2,12 @@
 
 The Pallas kernels run in interpret mode on the CPU; the oracle is the
 token-by-token scan ``ssd_sequential_ref``.  Shapes are small (8 heads,
-the kernels' head block) but keep what the kernels must get right:
+the chunk kernel's head block) but keep what the kernels must get right:
 chunk boundaries, a sequence that is not a whole number of chunks, a
-nonzero entering state, masked tokens (``dt = 0``) as identity steps and
-decays from exp(-0.05) down to below exp(-20) a token.
+nonzero entering state, masked tokens (``dt = 0``) as identity steps,
+decays from exp(-0.05) down to below exp(-20) a token, and the decode
+window's live rows: a row with no valid token is neither read nor
+written (its state bit for bit, NaN included) and its y is zero.
 
 Tolerances are relative to the reference's largest value: ``STATE_TOL``
 for the f32 state (a state rounded to bf16 once a token fails it, as
@@ -17,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.ssd import ops
+from repro.kernels.ssd import decode, ops
 from repro.kernels.ssd.decode import ssd_decode_window_pallas
 from repro.kernels.ssd.kernel import ssd_chunk_pallas
 from repro.kernels.ssd.ref import (random_inputs, rel_error, ssd_chunked_ref,
@@ -77,6 +79,104 @@ def test_decode_window_kernel_updates_its_layer_in_place(t):
     assert rel(out[1], s0) <= STATE_TOL
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(stack[0]))
     np.testing.assert_array_equal(np.asarray(out[2]), np.asarray(stack[2]))
+
+
+#: Live rows of a 32-row batch.
+LIVE = {"all": range(32), "none": (), "rows_0_5_31": (0, 5, 31),
+        "one": (12,)}
+
+
+def bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+def check_live_rows(live, t, heads=H, p=P):
+    """Layer 1 of a three-layer stack over 32 rows, ``live`` of them
+    live: live rows against the sequential scan; dead rows (one seeded
+    with NaN, all with nonzero dt) bit for bit, their y zero; the NaN in
+    no live row; the other layers untouched."""
+    x, dt, a, bm, cm, h0 = random_inputs((32, heads, p, N), t, seed=6)
+    dead = np.flatnonzero(~live)
+    if dead.size:
+        h0 = h0.at[dead[0], 0, 0, 0].set(jnp.nan)
+    stack = jnp.stack([h0 + 7.0, h0, h0 - 3.0])
+    y, out = ssd_decode_window_pallas(x, dt, a, bm, cm, stack, 1,
+                                      jnp.asarray(live), interpret=True)
+    if live.any():
+        y0, s0 = ssd_sequential_ref(x[live], dt[live], a, bm[live], cm[live],
+                                    h0[live])
+        assert rel(y[live], y0) <= OUT_TOL
+        assert rel(out[1][live], s0) <= STATE_TOL
+        assert np.all(np.isfinite(np.asarray(out[1][live])))
+    np.testing.assert_array_equal(bits(out[1][~live]), bits(h0[~live]))
+    assert not np.any(np.asarray(y[~live], np.float32))
+    np.testing.assert_array_equal(bits(out[::2]), bits(stack[::2]))
+
+
+@pytest.mark.parametrize("t", [1, 8, 64])
+@pytest.mark.parametrize("pattern", list(LIVE))
+def test_decode_window_walks_only_live_rows(pattern, t):
+    check_live_rows(np.isin(np.arange(32), LIVE[pattern]), t)
+
+
+@pytest.mark.parametrize("blocks", [1, 3])
+@pytest.mark.parametrize("pattern", list(LIVE))
+def test_grid_visits_only_live_rows(pattern, blocks):
+    """The index maps walk the live rows' head blocks in order, then
+    repeat the last one, for which Pallas moves nothing: no dead row's
+    block is visited.  With no live row every step maps to one block,
+    which the kernel copies through unchanged."""
+    live = np.isin(np.arange(32), LIVE[pattern])
+    rows, count = decode.live_rows(jnp.asarray(live))
+    seq = [tuple(int(v) for v in decode.live_block(
+        i, j, rows, count[0], blocks - 1))
+        for i in range(32) for j in range(blocks)]
+    walked = [(r, j) for r in np.flatnonzero(live) for j in range(blocks)]
+    assert seq[:len(walked)] == walked
+    assert set(seq[len(walked):]) <= {(walked or [(0, blocks - 1)])[-1]}
+
+
+@pytest.fixture
+def two_head_blocks(monkeypatch):
+    """A VMEM budget that fits 128 heads a step, not 256: a row of 256
+    heads takes two grid steps."""
+    monkeypatch.setattr(decode, "VMEM_BUDGET",
+                        decode.step_bytes(128, 1, 8, N))
+    assert decode.row_block(256, 1, 8, N) == 128
+    ssd_decode_window_pallas.clear_cache()
+    yield
+    ssd_decode_window_pallas.clear_cache()
+
+
+@pytest.mark.parametrize("pattern", list(LIVE))
+def test_decode_window_live_rows_over_head_blocks(pattern, two_head_blocks):
+    """Steps past the live count stay on the last live row's last head
+    block, and a row's blocks are walked in turn."""
+    check_live_rows(np.isin(np.arange(32), LIVE[pattern]), 1, heads=256,
+                    p=8)
+
+
+@pytest.mark.parametrize("t", [1, 8, 64])
+def test_granite_row_is_one_grid_step(t):
+    """At granite's widths (64 heads of 64, d_state 128) a row's whole
+    state is one block for every window length, within the budget."""
+    assert decode.row_block(64, t, 64, 128) == 64
+    assert decode.step_bytes(64, t, 64, 128) <= decode.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_ops_live_rows(use_kernel):
+    """Through the dispatch: rows whose tokens are all masked (dt = 0)
+    keep their state bit for bit, on the oracle (identity steps) and the
+    kernel (not walked); live rows' y agree."""
+    x, dt, a, bm, cm, h0 = inputs(3, seed=7)
+    live = jnp.asarray([False, True])
+    dt = jnp.where(live[:, None, None], dt, 0.0)
+    y, s = ops.ssd(x, dt, a, bm, cm, h0, live=live, decode=True,
+                   use_kernel=use_kernel)
+    y0, s0 = ssd_sequential_ref(x, dt, a, bm, cm, h0)
+    np.testing.assert_array_equal(bits(s[0]), bits(h0[0]))
+    assert rel(y[1], y0[1]) <= OUT_TOL and rel(s[1], s0[1]) <= STATE_TOL
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
